@@ -59,6 +59,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import runtime
+from repro.launch.compile_cache import use_compile_cache
 from repro.core.cim import CIMConfig
 from repro.core.kan_layer import KANSpec, init_kan_network, kan_network_apply
 from repro.core.kan_network_deploy import (
@@ -701,6 +702,7 @@ def run(batch: int = 128, repeats: int = 10, serve_requests: int = 4,
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--batch", type=int, default=128)
     ap.add_argument("--repeats", type=int, default=10)

@@ -37,9 +37,9 @@ def _bench_kernel(print_fn=print):
     t_ref = (time.perf_counter() - t0) / 10 * 1e6
     print_fn(f"kan_spline_ref_jit,{t_ref:.0f},us_per_call (B=256 F=128 O=128)")
 
-    out = kan_spline(codes, lut, wc, wb, spec, interpret=True)
+    out = kan_spline(codes, lut, wc, wb, spec)
     err = float(jnp.abs(out - ref(codes)).max())
-    print_fn(f"kan_spline_pallas_interpret,allclose_err,{err:.2e}")
+    print_fn(f"kan_spline_pallas,allclose_err,{err:.2e}")
 
 
 def _bench_runtime(print_fn=print):

@@ -35,11 +35,11 @@ def main():
     y_quant = kan_network_apply(None, x, kspec, quantized=True,
                                 qparams_list=qparams)
 
-    # 3) the Pallas TPU kernel (interpret mode on CPU), layer by layer
+    # 3) the Pallas TPU kernel (interpret mode off-TPU), layer by layer
     h = x
     for qp in qparams:
         codes = quantize_input(h, spec)
-        h = kan_spline_from_qparams(codes, qp, spec, interpret=True)
+        h = kan_spline_from_qparams(codes, qp, spec)
         if qp is not qparams[-1]:
             h = jnp.tanh(h)
     y_kernel = h
@@ -47,8 +47,7 @@ def main():
     # 4) the fused multi-layer pipeline: every layer in the Pallas kernel,
     #    inter-layer requantization fused, activations stay int codes
     y_fused = kan_network_apply(None, x, kspec, quantized=True,
-                                qparams_list=qparams, backend="pallas",
-                                interpret=True)
+                                qparams_list=qparams, backend="pallas")
 
     print("\nfloat    ", y_float[0, :5])
     print("quantized", y_quant[0, :5])

@@ -96,10 +96,7 @@ def resolve_layer_bits(n_bits, n_layers: int, grid_size: int) -> tuple:
 def lut_scale(spec: "ASPQuantSpec") -> float:
     """Dequantization scale of the SH-LUT int codes (``lut ~= lut_q * s``).
 
-    Derivable from the spec alone — bump peak over the code ceiling — so the
-    fused kernel can bake it as a trace-time f32 constant when unpacking
-    int4-packed LUT lanes (bit-exact with the deployed f32 table, which is
-    stored as ``f32(lut_q) * f32(scale)`` whenever ``lut_bits <= 4``).
+    Derivable from the spec alone — bump peak over the code ceiling.
     """
     K = spec.order
     qmax = 2**spec.lut_bits - 1

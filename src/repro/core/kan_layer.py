@@ -57,8 +57,8 @@ class KANSpec:
     or a per-layer tuple of widths (KANtize-style mixed precision) — one
     entry per layer, each independently PowerGap-validated.  A layer's
     ``lut_bits`` is clipped to its input width, so a 4-bit layer stores a
-    4-bit SH-LUT (and the kernel packs two LUT/weight codes per int8 lane,
-    see ``kernels.kan_spline.pipeline``).
+    4-bit SH-LUT (and its weight codes deploy two per int8 lane, see
+    ``kernels.kan_spline.pipeline``).
     """
 
     dims: tuple  # e.g. (17, 1, 14)

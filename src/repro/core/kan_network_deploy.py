@@ -27,8 +27,6 @@ from .. import runtime
 from ..kernels.kan_spline.pipeline import (
     PipelinePlan,
     pack_layer_weights,
-    pack_lut,
-    packs_lut,
     packs_weights,
     pad_layer_weights,
 )
@@ -52,7 +50,7 @@ class DeployedKAN:
 
     layers: tuple of per-layer weight dicts, already padded to the plan:
     {"lut", "wc", "wb"} dequantized f32 for 8-bit layers, or the int4-packed
-    {"lut"[, "lutp"], "wcp", "wscale", "wb"} form for <=4-bit layers (two
+    {"lut", "wcp", "wscale", "wb"} form for <=4-bit layers (two
     signed weight codes per int8 lane; the kernel decodes in-lane).
     specs/dims describe the logical network for the runtime backends.
     placement: the mesh this bundle's weights were placed on with
@@ -164,8 +162,6 @@ def _deploy(qparams_list, dims, specs, batch, *, residual_raw) -> DeployedKAN:
                 "lut": qp["lut"],
                 **pack_layer_weights(qp["c_q"], qp["c_scale"], wb, lp),
             }
-            if packs_lut(lp.spec):
-                layer["lutp"] = pack_lut(qp["lut_q"], lp.spec)
         else:
             wc, _ = _dequant_layer(qp)
             layer = {"lut": qp["lut"], **pad_layer_weights(wc, wb, lp)}

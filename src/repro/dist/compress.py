@@ -86,7 +86,7 @@ def compress_deployed_kan(dep) -> dict:
         for k, leaf in lw.items():
             a = np.asarray(jax.device_get(leaf))
             if a.dtype == np.int8:
-                # already int4-packed storage ("wcp"/"lutp"): ship verbatim
+                # already int4-packed storage ("wcp"): ship verbatim
                 entry[k] = a
             elif k.startswith("lut") or k == "wscale":
                 # tiny precision anchors: raw f32

@@ -187,8 +187,8 @@ def deployed_kan_pspecs(dep, mesh):
                 return P(*([None] * (a.ndim - 1) + ["model"]))
             return P(*([None] * a.ndim))
 
-        # key-generic over the deployed forms: SH-LUT leaves ("lut", and the
-        # int4-packed "lutp") replicate; every other leaf — "wc", or the
+        # key-generic over the deployed forms: the SH-LUT ("lut")
+        # replicates; every other leaf — "wc", or the
         # packed "wcp" + per-channel "wscale" row, and "wb" — carries its
         # output channels on the last dim and shards them on "model"
         return {

@@ -8,13 +8,15 @@ rescaled output accumulator — the FlashAttention recurrence (Dao et al.).
 
 Layout/grid conventions (the :mod:`.ops` wrapper produces these):
 
-  * q:    (B, Hkv, S, G, D) — the GQA query group G is folded next to the
-          query rows, so ONE KV head tile streamed from HBM serves its whole
-          group; in-kernel the q tile is reshaped to (block_q * G, D) rows.
+  * q:    (B, Hkv, S*G, D) — the GQA query group G is folded into the query
+          rows (row ``s*G + g``), so ONE KV head tile streamed from HBM
+          serves its whole group and every block stays 2-D in-kernel.
   * k, v: (B, Hkv, T, D)
-  * qpos/kpos: (B, S) / (B, T) int32 absolute positions.  Negative kpos
-          marks an invalid key (unwritten rolling-cache slot, padded tile) —
-          masked under EVERY kind; negative qpos rows finalize to exact 0.
+  * qpos: (B, S*G, 1) int32 — each query row's absolute position, as a
+          column so it broadcasts against a key row without a relayout.
+  * kpos: (B, 1, T) int32 absolute key positions.  Negative kpos marks an
+          invalid key (unwritten rolling-cache slot, padded tile) — masked
+          under EVERY kind; negative qpos rows finalize to exact 0.
   * grid: (B, Hkv, S/block_q, T/block_k) with the KV tile index innermost —
           scratch persists across the sequential KV sweep, is initialized at
           the first tile and finalized (guarded division) at the last.
@@ -47,6 +49,14 @@ _STAT_LANES = 128  # running m/l scratch is lane-replicated for TPU tiling
 KINDS = ("causal", "local", "full")
 
 
+def _dot(a, b, contract):
+    return jax.lax.dot_general(
+        a, b, (contract, ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, out_ref,
                   m_scr, l_scr, acc_scr, *,
                   kind: str, window: int, softcap: float, scale: float):
@@ -59,29 +69,23 @@ def _flash_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, out_ref,
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    bq, g, d = q_ref.shape[2], q_ref.shape[3], q_ref.shape[4]
-    bk = k_ref.shape[2]
-    q = q_ref[0, 0].reshape(bq * g, d).astype(jnp.float32)
+    q = q_ref[0, 0].astype(jnp.float32)  # (bq*G, d)
     k = k_ref[0, 0].astype(jnp.float32)  # (bk, d)
     v = v_ref[0, 0].astype(jnp.float32)  # (bk, d)
 
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale
+    s = _dot(q, k, ((1,), (1,))) * scale
     if softcap > 0.0:
         # softcap BEFORE masking — same op order as layers._sdpa_chunk
         s = jnp.tanh(s / softcap) * softcap
 
-    qp = qpos_ref[0]                     # (bq,) int32
-    kp = kpos_ref[0]                     # (bk,) int32
-    mask = (kp >= 0)[None, :]            # key validity, every kind
+    qp = qpos_ref[0]                     # (bq*G, 1) int32
+    kp = kpos_ref[0]                     # (1, bk) int32
+    mask = kp >= 0                       # key validity, every kind
     if kind in ("causal", "local"):
-        mask = mask & (kp[None, :] <= qp[:, None])
+        mask = mask & (kp <= qp)
     if kind == "local" and window > 0:
-        mask = mask & (kp[None, :] > qp[:, None] - window)
-    # (bq, bk) -> (bq*G, bk): the positional mask is per-KV-head, shared by
-    # the whole query group
-    mask = jnp.broadcast_to(mask[:, None, :], (bq, g, bk)).reshape(bq * g, bk)
+        mask = mask & (kp > qp - window)
+    mask = jnp.broadcast_to(mask, s.shape)
     s = jnp.where(mask, s, NEG_INF)
 
     m_prev = m_scr[:, :1]                # (bq*G, 1)
@@ -92,9 +96,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, out_ref,
     e = jnp.where(mask, jnp.exp(s - m_new), 0.0)
     alpha = jnp.exp(m_prev - m_new)      # rescale factor for the old state
     l_new = l_prev * alpha + jnp.sum(e, axis=-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        e, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    acc_scr[...] = acc_scr[...] * alpha + _dot(e, v, ((1,), (0,)))
     m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
     l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
@@ -104,20 +106,23 @@ def _flash_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, out_ref,
         # guarded denominator: fully-masked rows (padded queries, qpos < 0)
         # emit exact zeros instead of an average over garbage
         out = jnp.where(l > 0.0, acc_scr[...] / jnp.maximum(l, 1e-30), 0.0)
-        out_ref[0, 0] = out.reshape(bq, g, d).astype(out_ref.dtype)
+        out_ref[0, 0] = out.astype(out_ref.dtype)
 
 
-def flash_attention_fused(q, k, v, qpos, kpos, *, kind: str, window: int,
-                          softcap: float, scale: float, block_q: int,
-                          block_k: int, interpret: bool):
+def flash_attention_fused(q, k, v, qpos, kpos, *, group: int, kind: str,
+                          window: int, softcap: float, scale: float,
+                          block_q: int, block_k: int, interpret: bool):
     """The raw pallas_call on pre-tiled operands (see module docstring for
-    the layout contract).  S must divide by block_q and T by block_k —
+    the layout contract; ``group`` is G, the query rows per position).
+    S must divide by block_q and T by block_k —
     :func:`repro.kernels.attention.ops.flash_attention` pads and slices."""
     if kind not in KINDS:
         raise ValueError(f"kind {kind!r} not in {KINDS}")
-    b, hkv, s, g, d = q.shape
+    b, hkv, rows, d = q.shape
     t = k.shape[2]
+    s = rows // group
     assert s % block_q == 0 and t % block_k == 0, (s, t, block_q, block_k)
+    bqg = block_q * group
     grid = (b, hkv, s // block_q, t // block_k)
     kern = functools.partial(
         _flash_kernel, kind=kind, window=int(window),
@@ -127,22 +132,26 @@ def flash_attention_fused(q, k, v, qpos, kpos, *, kind: str, window: int,
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, g, d),
-                         lambda ib, ih, iq, ik: (ib, ih, iq, 0, 0)),
+            pl.BlockSpec((1, 1, bqg, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
             pl.BlockSpec((1, 1, block_k, d),
                          lambda ib, ih, iq, ik: (ib, ih, ik, 0)),
             pl.BlockSpec((1, 1, block_k, d),
                          lambda ib, ih, iq, ik: (ib, ih, ik, 0)),
-            pl.BlockSpec((1, block_q), lambda ib, ih, iq, ik: (ib, iq)),
-            pl.BlockSpec((1, block_k), lambda ib, ih, iq, ik: (ib, ik)),
+            pl.BlockSpec((1, bqg, 1), lambda ib, ih, iq, ik: (ib, iq, 0)),
+            pl.BlockSpec((1, 1, block_k), lambda ib, ih, iq, ik: (ib, 0, ik)),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_q, g, d),
-                               lambda ib, ih, iq, ik: (ib, ih, iq, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, s, g, d), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, bqg, d),
+                               lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q * g, _STAT_LANES), jnp.float32),  # running m
-            pltpu.VMEM((block_q * g, _STAT_LANES), jnp.float32),  # running l
-            pltpu.VMEM((block_q * g, d), jnp.float32),            # output acc
+            pltpu.VMEM((bqg, _STAT_LANES), jnp.float32),  # running m
+            pltpu.VMEM((bqg, _STAT_LANES), jnp.float32),  # running l
+            pltpu.VMEM((bqg, d), jnp.float32),            # output acc
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+        ),
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v, qpos, kpos)

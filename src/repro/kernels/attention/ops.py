@@ -15,15 +15,10 @@ import math
 import jax
 import jax.numpy as jnp
 
+from .. import default_interpret
 from .kernel import KINDS, flash_attention_fused
 
 __all__ = ["flash_attention"]
-
-
-def _default_interpret() -> bool:
-    # same probe as runtime.default_interpret(), duplicated locally so the
-    # kernels package stays import-independent of the runtime package
-    return jax.default_backend() != "tpu"
 
 
 def _round_up(n: int, m: int) -> int:
@@ -67,7 +62,7 @@ def flash_attention(q, k, v, *, kind: str = "causal", qpos=None, kpos=None,
     if kind not in KINDS:
         raise ValueError(f"kind {kind!r} not in {KINDS}")
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     b, s, hq, d = q.shape
     t, hkv = k.shape[1], k.shape[2]
     if hq % hkv:
@@ -83,21 +78,23 @@ def flash_attention(q, k, v, *, kind: str = "causal", qpos=None, kpos=None,
     bk = min(block_k, _round_up(t, 8))
     sp, tp = _round_up(s, bq), _round_up(t, bk)
 
-    # (B, S, Hq, D) -> (B, Hkv, S, G, D): group rides next to the query rows
-    qr = q.reshape(b, s, hkv, g, d).transpose(0, 2, 1, 3, 4)
-    kr = k.transpose(0, 2, 1, 3)
-    vr = v.transpose(0, 2, 1, 3)
     if sp != s:
-        qr = jnp.pad(qr, ((0, 0), (0, 0), (0, sp - s), (0, 0), (0, 0)))
+        q = jnp.pad(q, ((0, 0), (0, sp - s), (0, 0), (0, 0)))
         qpos = jnp.pad(qpos, ((0, 0), (0, sp - s)), constant_values=-1)
     if tp != t:
-        kr = jnp.pad(kr, ((0, 0), (0, 0), (0, tp - t), (0, 0)))
-        vr = jnp.pad(vr, ((0, 0), (0, 0), (0, tp - t), (0, 0)))
+        k = jnp.pad(k, ((0, 0), (0, tp - t), (0, 0), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, tp - t), (0, 0), (0, 0)))
         kpos = jnp.pad(kpos, ((0, 0), (0, tp - t)), constant_values=-1)
+    # (B, S, Hq, D) -> (B, Hkv, S*G, D): row s*G + g is query position s of
+    # group member g, so one KV head tile serves its whole group
+    qr = q.reshape(b, sp, hkv, g, d).transpose(0, 2, 1, 3, 4)
+    qr = qr.reshape(b, hkv, sp * g, d)
+    qrow = jnp.repeat(qpos, g, axis=1)[:, :, None]  # (B, S*G, 1)
 
     out = flash_attention_fused(
-        qr, kr, vr, qpos, kpos, kind=kind, window=window, softcap=softcap,
+        qr, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), qrow,
+        kpos[:, None, :], group=g, kind=kind, window=window, softcap=softcap,
         scale=scale, block_q=bq, block_k=bk, interpret=interpret,
     )
-    out = out[:, :, :s]
+    out = out.reshape(b, hkv, sp, g, d)[:, :, :s]
     return out.transpose(0, 2, 1, 3, 4).reshape(b, s, hq, d)

@@ -1,22 +1,18 @@
 """Pallas TPU kernel: fused ASP spline-basis construction + banded matmul.
 
-TPU-native realization of the paper's B(X) datapath (DESIGN.md §2):
+TPU-native realization of the paper's B(X) datapath for ONE layer:
 
-  * PowerGap bit split -> shift/mask on the VPU (replaces silicon decoders)
-  * SH-LUT retrieval   -> one-hot x (2**LD, K+1) matmul (replaces TG-MUXes;
-                          no per-element dynamic gather touches HBM)
-  * banded basis placement -> iota-compare/select against the interval index
+  * PowerGap bit split, SH-LUT retrieval and banded basis placement ->
+    :func:`repro.kernels.kan_spline.pipeline.banded_basis` (shared with the
+    fused multi-layer pipeline)
   * spline MAC         -> dense (bB, bF*(G+K)) x (bF*(G+K), bO) on the MXU
                           ("B(X) on word lines x c' in the RRAM array")
   * the w_b * relu(x) residual branch is fused into the same tile
 
 Grid: (B/bB, O/bO, F/bF); the F axis is the contraction — partial products
 accumulate into the output tile (revisited across the last grid dimension,
-per the TPU grid-iteration guarantee).
-
-VMEM per step ~ bB*bF*4 (codes) + 2**LD*(K+1)*4 (LUT) + bF*NB*bO*4 (wc tile)
-+ bB*NB*bF*4 (basis tile) + bB*bO*4 (acc): with bB=bO=128, bF=256, NB=8 the
-working set is ~3.3 MiB — inside the 16 MiB v5e VMEM budget.
+per the TPU grid-iteration guarantee).  The VMEM working set is the
+pipeline's (:func:`repro.kernels.kan_spline.pipeline.vmem_bytes`).
 """
 
 from __future__ import annotations
@@ -26,66 +22,29 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ...core.asp_quant import ASPQuantSpec
+from .pipeline import _VMEM_LIMIT, _dot, banded_basis
 
 
 def _kan_spline_kernel(
     codes_ref,  # (bB, bF) int32
-    lut_ref,    # (2**LD, K+1) f32
+    lut_ref,    # (2**LD, K+1) f32, SMEM
     wc_ref,     # (bF * NB, bO) f32/bf16
     wb_ref,     # (bF, bO) f32/bf16
     out_ref,    # (bB, bO) f32
     *,
     spec: ASPQuantSpec,
-    block_f: int,
 ):
     k_step = pl.program_id(2)
-    nb = spec.num_basis
-    kk = spec.order + 1
-    n_local = spec.codes_per_interval
-
     codes = codes_ref[...]
-    bb, bf = codes.shape
-
-    # --- PowerGap bit split (VPU shift/mask; the "decoder" is free)
-    g = jax.lax.shift_right_logical(codes, spec.ld)          # interval index
-    local = jax.lax.bitwise_and(codes, n_local - 1)          # offset in interval
-
-    # --- SH-LUT retrieval as one-hot matmul (2**LD is tiny: <= 32)
-    flat_local = local.reshape(bb * bf, 1)
-    iota_l = jax.lax.broadcasted_iota(jnp.int32, (bb * bf, n_local), 1)
-    onehot = (iota_l == flat_local).astype(jnp.float32)
-    lutv = jax.lax.dot_general(
-        onehot,
-        lut_ref[...].astype(jnp.float32),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).reshape(bb, bf, kk)                                    # (bB, bF, K+1)
-
-    # --- banded placement: basis[b, f, i] = lutv[b, f, i - g] if 0<=i-g<=K
-    iota_nb = jax.lax.broadcasted_iota(jnp.int32, (bb, bf, nb), 2)
-    d = iota_nb - g[..., None]
-    basis = jnp.zeros((bb, bf, nb), jnp.float32)
-    for dd in range(kk):  # static unroll: K+1 selects
-        basis = basis + jnp.where(d == dd, lutv[..., dd][..., None], 0.0)
-
-    # --- spline MAC on the MXU
-    acc = jax.lax.dot_general(
-        basis.reshape(bb, bf * nb),
-        wc_ref[...].astype(jnp.float32),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    acc = _dot(banded_basis(codes, lut_ref, spec),
+               wc_ref[...].astype(jnp.float32))
 
     # --- fused residual branch: relu(deq(codes)) @ wb
     xdeq = spec.lo + codes.astype(jnp.float32) * spec.code_step
-    acc = acc + jax.lax.dot_general(
-        jnp.maximum(xdeq, 0.0),
-        wb_ref[...].astype(jnp.float32),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    acc = acc + _dot(jnp.maximum(xdeq, 0.0), wb_ref[...].astype(jnp.float32))
 
     @pl.when(k_step == 0)
     def _init():
@@ -116,19 +75,22 @@ def kan_spline_pallas(
     assert bsz % block_b == 0 and o % block_o == 0 and f % block_f == 0
 
     grid = (bsz // block_b, o // block_o, f // block_f)
-    kernel = functools.partial(_kan_spline_kernel, spec=spec, block_f=block_f)
+    kernel = functools.partial(_kan_spline_kernel, spec=spec)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_b, block_f), lambda i, j, k: (i, k)),
-            pl.BlockSpec(
-                (spec.codes_per_interval, spec.order + 1), lambda i, j, k: (0, 0)
-            ),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((block_f * nb, block_o), lambda i, j, k: (k, j)),
             pl.BlockSpec((block_f, block_o), lambda i, j, k: (k, j)),
         ],
         out_specs=pl.BlockSpec((block_b, block_o), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bsz, o), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
         interpret=interpret,
-    )(codes, lut, wc, wb)
+        name="kan_spline",
+    )(codes, lut.astype(jnp.float32), wc, wb)

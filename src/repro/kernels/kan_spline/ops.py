@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from ...core.asp_quant import ASPQuantSpec
+from .. import default_interpret
 from .kernel import kan_spline_pallas
 
 __all__ = ["kan_spline", "kan_spline_from_qparams"]
@@ -37,8 +38,13 @@ def kan_spline(
     block_b: int = 128,
     block_o: int = 128,
     block_f: int = 128,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
+    """Pad to block multiples, run the kernel, slice back to (B, O).
+    ``interpret=None`` compiles on a TPU and interprets elsewhere
+    (:func:`repro.kernels.default_interpret`)."""
+    if interpret is None:
+        interpret = default_interpret()
     bsz, f = codes.shape
     o = wc.shape[-1]
     nb = spec.num_basis
@@ -60,7 +66,8 @@ def kan_spline(
 
 
 def kan_spline_from_qparams(
-    codes: jax.Array, qparams: dict, spec: ASPQuantSpec, *, interpret: bool = False
+    codes: jax.Array, qparams: dict, spec: ASPQuantSpec, *,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Run the kernel from quantize_kan_layer output (dequantized weights)."""
     wc = qparams["c_q"].astype(jnp.float32) * qparams["c_scale"]

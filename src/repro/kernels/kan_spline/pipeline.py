@@ -43,8 +43,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ...core.asp_quant import ASPQuantSpec, lut_scale
+from ...core.asp_quant import ASPQuantSpec
 
 __all__ = [
     "LayerPlan",
@@ -56,12 +57,12 @@ __all__ = [
     "validate_plan",
     "weight_bits",
     "packs_weights",
-    "packs_lut",
     "layer_weight_keys",
     "pad_layer_weights",
     "pack_layer_weights",
-    "pack_lut",
     "unpacked_wc",
+    "banded_basis",
+    "vmem_bytes",
     "run_pipeline_layer",
     "kan_pipeline",
     "kan_pipeline_impl",
@@ -111,10 +112,38 @@ class PipelinePlan:
     layers: tuple               # tuple[LayerPlan, ...]
 
 
-# VMEM working-set ceiling for the basis tile (bB, bF, G+K) f32; bf is halved
-# until the tile fits.  4 MiB leaves room for the wc tile + double buffering
-# inside the 16 MiB v5e budget (see kernel.py header for the full budget).
-_BASIS_TILE_BUDGET = 4 * 1024 * 1024
+# VMEM: the limit every pipeline kernel is compiled with, and the share of
+# it a plan's estimated working set (:func:`vmem_bytes`) may take.  A TPU
+# v5e core has 128 MiB of VMEM but a 16 MiB default scoped limit, which the
+# G=68 weight tile (128*71 x 128 f32, double-buffered) alone would nearly fill.
+_VMEM_LIMIT = 64 * 1024 * 1024
+_VMEM_BUDGET = 48 * 1024 * 1024
+
+
+def _tile_bytes(rows: int, cols: int, itemsize: int = 4) -> int:
+    """VMEM bytes of a 2-D buffer once padded to the TPU's native tile
+    (8 sublanes of 32-bit words x 128 lanes; narrower dtypes pack more rows
+    per sublane)."""
+    return (_round_up(rows, 8 * 4 // itemsize) * _round_up(cols, 128)
+            * itemsize)
+
+
+def vmem_bytes(bb: int, bf: int, bo: int, spec: ASPQuantSpec) -> int:
+    """Estimated VMEM working set of one fused-layer grid step.
+
+    Counts every block with lane/sublane padding: the double-buffered
+    inputs and outputs (codes, raw activation, f32 weight tile, wb, psum
+    noise, y, out codes — the worst case over backends and packing), the
+    unpacked int4 weight scratch, the 0/1 expansion matrix, the
+    (bb, bf*(G+K)) band/basis planes and the K+1 per-feature LUT planes.
+    """
+    nbf = bf * spec.num_basis
+    io = (2 * _tile_bytes(bb, bf) + _tile_bytes(nbf, bo) + _tile_bytes(bf, bo)
+          + 3 * _tile_bytes(bb, bo))
+    temps = (_tile_bytes(nbf, bo) + _tile_bytes(bf, nbf)
+             + 3 * _tile_bytes(bb, nbf)
+             + (spec.order + 4) * _tile_bytes(bb, bf))
+    return 2 * io + temps
 
 
 def _is_pow2(x: int) -> bool:
@@ -149,7 +178,6 @@ def make_pipeline_plan(
     *,
     residual_raw: bool = False,
     max_block_b: int = 128,
-    max_block_f: int = 128,
     tile_overrides=None,
 ) -> PipelinePlan:
     """Choose block sizes + padded dims for a whole stack from shapes alone.
@@ -163,8 +191,8 @@ def make_pipeline_plan(
     never the padded dims ``fp``/``op`` — deployed weight bundles padded
     under the heuristic plan stay valid verbatim under any tuned plan, and
     the 128-padded inter-layer boundary contract is untouched.  Invalid
-    overrides (non-power-of-two, not dividing the padded dim, basis tile
-    over the VMEM budget) raise ``ValueError``.
+    overrides (non-power-of-two, not dividing the padded dim, a working
+    set over the VMEM budget) raise ``ValueError``.
     """
     n_layers = len(dims) - 1
     if len(specs) != n_layers:
@@ -183,15 +211,10 @@ def make_pipeline_plan(
     for li in range(n_layers):
         f, o = dims[li], dims[li + 1]
         spec = specs[li]
-        nb = spec.num_basis
-        # bf must divide the boundary pad (128) when fed by a previous layer,
-        # so it is a power of two <= 128; shrink until the basis tile fits.
-        # The budget uses the WORST-CASE bb (max_block_b), not the actual bb,
-        # so fp/op are batch-independent and DeployedKAN.replan can swap the
-        # batch without re-padding weights.
+        # Features and outputs ride the 128 lanes, so bf/bo are 128 or the
+        # whole (small) padded dim: fp/op depend on the layer alone and
+        # DeployedKAN.replan can swap the batch without re-padding weights.
         bf = _pow2_at_least(f) if li == 0 else 128
-        while bf > 8 and max_block_b * bf * nb * 4 > _BASIS_TILE_BUDGET:
-            bf //= 2
         bo = 128
         fp = _round_up(f, bf) if li == 0 else _round_up(f, 128)
         op = _round_up(o, bo)
@@ -205,11 +228,6 @@ def make_pipeline_plan(
                 raise ValueError(
                     f"layer {li}: bf override {bf_c} invalid for fp={fp}"
                 )
-            if bb * bf_c * nb * 4 > _BASIS_TILE_BUDGET:
-                raise ValueError(
-                    f"layer {li}: basis tile {bb}x{bf_c}x{nb} exceeds the "
-                    "VMEM budget"
-                )
             bo, bf = bo_c, bf_c
         layers.append(
             LayerPlan(
@@ -220,7 +238,29 @@ def make_pipeline_plan(
                 residual_raw=residual_raw,
             )
         )
+    if overrides is None:
+        # the batch block is the one free dim: halve it until every layer's
+        # working set fits
+        while bb > 8 and any(
+            vmem_bytes(bb, lp.bf, lp.bo, lp.spec) > _VMEM_BUDGET
+            for lp in layers
+        ):
+            bb = _round_up(bb // 2, 8)
+        layers = [dataclasses.replace(lp, bb=bb) for lp in layers]
+        bp = _round_up(batch, bb)
+    for li, lp in enumerate(layers):
+        _check_vmem(li, lp)
     return PipelinePlan(b=batch, bp=bp, layers=tuple(layers))
+
+
+def _check_vmem(li: int, lp: LayerPlan) -> None:
+    need = vmem_bytes(lp.bb, lp.bf, lp.bo, lp.spec)
+    if need > _VMEM_BUDGET:
+        raise ValueError(
+            f"layer {li}: tile {lp.bb}x{lp.bf}x{lp.spec.num_basis} -> "
+            f"{lp.bo} needs ~{need >> 20} MiB of VMEM, over the "
+            f"{_VMEM_BUDGET >> 20} MiB budget"
+        )
 
 
 def model_shardable(op: int, model_size: int) -> bool:
@@ -318,11 +358,7 @@ def validate_plan(plan: PipelinePlan) -> None:
             raise ValueError(
                 f"layer {li}: boundary op={lp.op} not 128-padded"
             )
-        if lp.bb * lp.bf * nb * 4 > _BASIS_TILE_BUDGET:
-            raise ValueError(
-                f"layer {li}: basis tile {lp.bb}x{lp.bf}x{nb} exceeds the "
-                "VMEM budget"
-            )
+        _check_vmem(li, lp)
         prev_op = lp.op
 
 
@@ -367,11 +403,6 @@ def packs_weights(spec: ASPQuantSpec) -> bool:
     return weight_bits(spec) <= 4
 
 
-def packs_lut(spec: ASPQuantSpec) -> bool:
-    """True when the layer's SH-LUT codes int4-pack."""
-    return spec.lut_bits <= 4
-
-
 def layer_weight_keys(lp: LayerPlan) -> tuple:
     """The deployed weight-dict keys this layer's plan implies.
 
@@ -380,8 +411,6 @@ def layer_weight_keys(lp: LayerPlan) -> tuple:
     everything else shards its output-channel dim on "model").
     """
     keys = ["lut"]
-    if packs_lut(lp.spec):
-        keys.append("lutp")
     if packs_weights(lp.spec):
         keys += ["wcp", "wscale"]
     else:
@@ -432,19 +461,6 @@ def pack_layer_weights(c_q: jax.Array, c_scale: jax.Array,
     return {"wcp": wcp, "wscale": wscale, "wb": wb_p}
 
 
-def pack_lut(lut_q: jax.Array, spec: ASPQuantSpec) -> jax.Array:
-    """Pack the (2**LD, K+1) unsigned SH-LUT codes two-per-lane on K+1.
-
-    Odd K+1 pads one zero column before pairing; the kernel unpacks and
-    slices back to K+1.  Codes are unsigned nibbles (lut_bits <= 4).
-    """
-    kk = spec.order + 1
-    q = jnp.asarray(lut_q, jnp.int32)
-    if kk % 2:
-        q = jnp.pad(q, ((0, 0), (0, 1)))
-    return _pack_nibbles(q[:, 0::2], q[:, 1::2])
-
-
 def unpacked_wc(lw: dict, lp: LayerPlan) -> jax.Array:
     """The padded f32 banded matrix of a deployed layer, packed or not.
 
@@ -468,25 +484,83 @@ def unpacked_wc(lw: dict, lp: LayerPlan) -> jax.Array:
 # ----------------------------------------------------------------------------
 
 
+def _dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    """(M, K) x (K, N) in full f32: on the TPU a default-precision f32 dot
+    is one bf16 pass, which would round the LUT values and the weights."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+
+
+def banded_basis(codes: jax.Array, lut_ref, spec: ASPQuantSpec) -> jax.Array:
+    """In-kernel ASP datapath up to the MAC operand.
+
+    ``codes``: (bb, bf) int32 tile; ``lut_ref``: the (2**LD, K+1) f32
+    SH-LUT, in SMEM.  Returns the (bb, bf*(G+K)) f32 banded basis laid out
+    like the deployed weight rows (feature-major, column f*(G+K) + n).
+
+    Every array stays 2-D with the features on the lanes, so this lowers
+    to Mosaic without lane<->sublane reshapes:
+
+      * PowerGap bit split -> shift/mask on the VPU;
+      * SH-LUT retrieval -> one (bb, bf) plane per band offset i, selected
+        per element from the LUT's scalars (2**LD is tiny: <= 32);
+      * banded placement -> an exact 0/1 expansion matmul spreads each
+        feature's planes over its G+K columns, and the band offset n - g of
+        each column picks the plane it takes (zero outside 0..K).
+    """
+    nb = spec.num_basis
+    kk = spec.order + 1
+    n_local = spec.codes_per_interval
+    bb, bf = codes.shape
+    nbf = bf * nb
+
+    g = jax.lax.shift_right_logical(codes, spec.ld)
+    local = jax.lax.bitwise_and(codes, n_local - 1)
+
+    lutv = [jnp.zeros((bb, bf), jnp.float32) for _ in range(kk)]
+    for l in range(n_local):
+        hit = local == l
+        for i in range(kk):
+            lutv[i] = jnp.where(hit, lut_ref[l, i], lutv[i])
+
+    row = jax.lax.broadcasted_iota(jnp.int32, (bf, nbf), 0) * nb
+    col = jax.lax.broadcasted_iota(jnp.int32, (bf, nbf), 1)
+    spread = ((col >= row) & (col < row + nb)).astype(jnp.float32)
+    first = jax.lax.broadcasted_iota(jnp.int32, (bb, bf), 1) * nb + g
+    band = (jax.lax.broadcasted_iota(jnp.int32, (bb, nbf), 1)
+            - _dot(first.astype(jnp.float32), spread).astype(jnp.int32))
+    basis = jnp.zeros((bb, nbf), jnp.float32)
+    for i in range(kk):  # static unroll: K+1 disjoint selects
+        basis = jnp.where(band == i, _dot(lutv[i], spread), basis)
+    return basis
+
+
 def _pipeline_layer_kernel(
     *refs,
     lp: LayerPlan,
     has_psum_noise: bool = False,
     packed_w: bool = False,
-    packed_lut: bool = False,
 ):
     """One KAN layer tile + (optionally) the fused inter-layer requantizer.
 
-    Ref order: codes, [xraw], lut | lutp, wc | (wcp, wscale), wb,
-    [psum_noise], y_out, [codes_out].
+    Ref order: codes, [xraw], lut (SMEM), wc | (wcp, wscale), wb,
+    [psum_noise], y_out, [codes_out], [wq scratch (packed_w only)].
     Grid: (Bp/bb, Op/bo, Fp/bf); the F axis (last) is the contraction —
     y_out accumulates across it, the boundary fires on the final step.
 
-    ``packed_w`` / ``packed_lut`` (static, from the deployed dict's keys):
-    the weight / LUT operand arrives as two int4 codes per int8 lane and is
-    unpacked HERE, inside the contraction — int32 nibble extract, then
-    f32 code x f32 scale, the exact product the unpacked deployment stores,
-    so the packed MAC is bit-identical to the unpacked one.
+    The datapath up to the MAC is :func:`banded_basis`; the spline MAC is
+    then ONE (bb, bf*(G+K)) x (bf*(G+K), bo) dot over the deployed row
+    order, the same contraction the reference runs.
+
+    ``packed_w`` (static, from the deployed dict's keys): the weight operand
+    arrives as two int4 codes per int8 lane and is unpacked HERE — int32
+    nibble extract, then f32 code x f32 scale, the exact product the
+    unpacked deployment stores — into a VMEM tile in contraction order
+    (strided row stores), so the packed MAC is bit-identical to the
+    unpacked one.
 
     ``psum_noise`` is the ACIM backend's hook: a precomputed (bb, bo) f32
     perturbation (the macro's partial-sum error, already scaled for the
@@ -510,85 +584,39 @@ def _pipeline_layer_kernel(
     if has_psum_noise:
         noise_ref = refs[idx]; idx += 1
     y_ref = refs[idx]; idx += 1
-    codes_out_ref = refs[idx] if lp.emit_codes else None
+    codes_out_ref = None
+    if lp.emit_codes:
+        codes_out_ref = refs[idx]; idx += 1
+    wq_ref = refs[idx] if packed_w else None
 
     spec = lp.spec
     k_step = pl.program_id(2)
     n_k = pl.num_programs(2)
-    nb = spec.num_basis
-    kk = spec.order + 1
-    n_local = spec.codes_per_interval
 
     codes = codes_ref[...]
-    bb, bf = codes.shape
-
-    # --- PowerGap bit split (VPU shift/mask; the "decoder" is free)
-    g = jax.lax.shift_right_logical(codes, spec.ld)
-    local = jax.lax.bitwise_and(codes, n_local - 1)
-
-    if packed_lut:
-        # two unsigned LUT nibbles per lane along K+1: decode with the
-        # trace-time scale constant (== the deployed f32 table's scale)
-        p32 = lut_ref[...].astype(jnp.int32)
-        lo_n = jax.lax.bitwise_and(p32, 0xF)
-        hi_n = jax.lax.bitwise_and(
-            jax.lax.shift_right_logical(p32, 4), 0xF
-        )
-        lut_tile = jnp.stack([lo_n, hi_n], axis=-1).reshape(
-            n_local, 2 * p32.shape[1]
-        )[:, :kk].astype(jnp.float32) * jnp.float32(lut_scale(spec))
-    else:
-        lut_tile = lut_ref[...].astype(jnp.float32)
-
-    # --- SH-LUT retrieval as one-hot matmul (2**LD is tiny: <= 32)
-    flat_local = local.reshape(bb * bf, 1)
-    iota_l = jax.lax.broadcasted_iota(jnp.int32, (bb * bf, n_local), 1)
-    onehot = (iota_l == flat_local).astype(jnp.float32)
-    lutv = jax.lax.dot_general(
-        onehot,
-        lut_tile,
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).reshape(bb, bf, kk)
-
-    # --- banded placement: basis[b, f, i] = lutv[b, f, i - g] if 0<=i-g<=K
-    iota_nb = jax.lax.broadcasted_iota(jnp.int32, (bb, bf, nb), 2)
-    d = iota_nb - g[..., None]
-    basis = jnp.zeros((bb, bf, nb), jnp.float32)
-    for dd in range(kk):  # static unroll: K+1 selects
-        basis = basis + jnp.where(d == dd, lutv[..., dd][..., None], 0.0)
+    basis = banded_basis(codes, lut_ref, spec)
 
     # --- spline MAC on the MXU
     if packed_w:
-        # unpack two signed int4 row-codes per lane: row 2r from the low
-        # nibble, row 2r+1 from the high nibble, interleaved back into
-        # contraction order, then decoded against the per-channel scales
+        # row 2r from the low nibble, row 2r+1 from the high nibble
         p32 = wc_ref[...].astype(jnp.int32)
-        half, bo_w = p32.shape
-        wq = jnp.stack(
-            [_unpack_lo_nibble(p32), _unpack_hi_nibble(p32)], axis=1
-        ).reshape(2 * half, bo_w)
-        wc_tile = wq.astype(jnp.float32) * wscale_ref[...].astype(jnp.float32)
+        half = p32.shape[0]
+        scale = wscale_ref[...].astype(jnp.float32)
+        wq_ref[pl.ds(0, half, stride=2), :] = (
+            _unpack_lo_nibble(p32).astype(jnp.float32) * scale)
+        wq_ref[pl.ds(1, half, stride=2), :] = (
+            _unpack_hi_nibble(p32).astype(jnp.float32) * scale)
+        wc_tile = wq_ref[...]
     else:
         wc_tile = wc_ref[...].astype(jnp.float32)
-    acc = jax.lax.dot_general(
-        basis.reshape(bb, bf * nb),
-        wc_tile,
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    acc = _dot(basis, wc_tile)
 
     # --- fused residual branch
     if lp.residual_raw:
         resid = xraw_ref[...].astype(jnp.float32)
     else:
         resid = spec.lo + codes.astype(jnp.float32) * spec.code_step
-    acc = acc + jax.lax.dot_general(
-        jnp.maximum(resid, 0.0),
-        wb_ref[...].astype(jnp.float32),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    acc = acc + _dot(jnp.maximum(resid, 0.0), wb_ref[...].astype(jnp.float32))
 
     @pl.when(k_step == 0)
     def _init():
@@ -633,7 +661,6 @@ def _run_layer(
     # static pytree structure): the acim backend's IR-drop path substitutes
     # an unpacked f32 dict for a packed layer and the kernel follows.
     packed_w = "wcp" in lw
-    packed_lut = "lutp" in lw
     assert codes.shape == (bp, lp.fp), (codes.shape, bp, lp.fp)
     if packed_w:
         assert lw["wcp"].shape == (lp.fp * nb // 2, lp.op), (
@@ -649,17 +676,10 @@ def _run_layer(
     if lp.residual_raw:
         in_specs.append(pl.BlockSpec((lp.bb, lp.bf), lambda i, j, k: (i, k)))
         inputs.append(xraw)
-    if packed_lut:
-        kk_half = (spec.order + 1 + 1) // 2
-        in_specs.append(pl.BlockSpec(
-            (spec.codes_per_interval, kk_half), lambda i, j, k: (0, 0)
-        ))
-        inputs.append(lw["lutp"])
-    else:
-        in_specs.append(pl.BlockSpec(
-            (spec.codes_per_interval, spec.order + 1), lambda i, j, k: (0, 0)
-        ))
-        inputs.append(lw["lut"])
+    # the (2**LD, K+1) SH-LUT is read as scalars
+    in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+    inputs.append(lw["lut"].astype(jnp.float32))
+    scratch = []
     if packed_w:
         # bf >= 8 keeps bf*nb even, so every contraction block owns whole
         # packed lanes and the (k, j) index map stays contiguous
@@ -668,6 +688,7 @@ def _run_layer(
             pl.BlockSpec((1, lp.bo), lambda i, j, k: (0, j)),
         ]
         inputs += [lw["wcp"], lw["wscale"]]
+        scratch.append(pltpu.VMEM((lp.bf * nb, lp.bo), jnp.float32))
     else:
         in_specs.append(pl.BlockSpec((lp.bf * nb, lp.bo), lambda i, j, k: (k, j)))
         inputs.append(lw["wc"])
@@ -686,7 +707,7 @@ def _run_layer(
 
     kernel = functools.partial(
         _pipeline_layer_kernel, lp=lp, has_psum_noise=psum_noise is not None,
-        packed_w=packed_w, packed_lut=packed_lut,
+        packed_w=packed_w,
     )
     outs = pl.pallas_call(
         kernel,
@@ -694,7 +715,13 @@ def _run_layer(
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
         interpret=interpret,
+        name="kan_pipeline",
     )(*inputs)
     if lp.emit_codes:
         return outs[0], outs[1]

@@ -13,22 +13,9 @@ __all__ = ["make_production_mesh", "make_local_mesh", "parse_mesh_spec"]
 
 
 def _make_mesh(shape, axes):
-    """Version-compatible mesh construction: axis_types / AxisType only
-    exist on newer jax; fall back through the older APIs."""
-    try:
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    except (AttributeError, TypeError):
-        pass
-    try:
-        return jax.make_mesh(shape, axes)
-    except AttributeError:
-        import numpy as np
-
-        n = int(np.prod(shape))
-        devs = np.asarray(jax.devices()[:n]).reshape(shape)
-        return jax.sharding.Mesh(devs, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -48,9 +48,11 @@ from ..configs.registry import smoke_config
 from ..models.model import init_params
 from ..serve.engine import Request, ServeEngine
 from ..serve.scheduler import QueueFull, SamplingParams, Scheduler
+from .compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--requests", type=int, default=8)

@@ -19,9 +19,11 @@ import jax
 from ..configs.registry import get_config, smoke_config
 from ..data.lm_data import DataConfig
 from ..train.loop import TrainLoop
+from .compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",

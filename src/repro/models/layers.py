@@ -56,10 +56,7 @@ def set_activation_spec(spec):
 def constrain_act(x):
     if _ACT_SPEC is None or x.ndim < 2:
         return x
-    try:
-        return jax.lax.with_sharding_constraint(x, _ACT_SPEC)
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, _ACT_SPEC)
 
 
 def init_rmsnorm(d: int):
@@ -220,13 +217,40 @@ _FLASH_KIND = {"global": "causal", "local": "local",
 
 
 def _sdpa_flash(q, k, v, cfg: ModelConfig, kind: str, qpos, kpos):
-    """The fused Pallas flash-attention path (backend "flash")."""
-    from ..kernels.attention import flash_attention
+    """The fused Pallas flash-attention path (backend "flash").
 
-    out = flash_attention(
-        q, k, v, kind=_FLASH_KIND[kind], qpos=qpos, kpos=kpos,
-        window=cfg.window_size, softcap=cfg.attn_logit_softcap,
-    )
+    XLA cannot partition a Mosaic kernel, so under a bound mesh
+    (``runtime.use_mesh`` — the serving engine's scope) the kernel runs in
+    a ``shard_map``: batch on "data" and KV heads (with their query groups)
+    on "model" wherever the axis divides them, replicated otherwise."""
+    from ..kernels.attention import flash_attention
+    from ..runtime.meshexec import mesh_axis_sizes, resolve_mesh
+
+    def call(q, k, v, qpos, kpos):
+        return flash_attention(
+            q, k, v, kind=_FLASH_KIND[kind], qpos=qpos, kpos=kpos,
+            window=cfg.window_size, softcap=cfg.attn_logit_softcap,
+        )
+
+    mesh = resolve_mesh()
+    if mesh is None:
+        return call(q, k, v, qpos, kpos).astype(v.dtype)
+    b, s = q.shape[:2]
+    t, hkv = k.shape[1], k.shape[2]
+    qpos = jnp.arange(s) + (t - s) if qpos is None else qpos
+    kpos = jnp.arange(t) if kpos is None else kpos
+    qpos = jnp.broadcast_to(jnp.atleast_2d(jnp.asarray(qpos, jnp.int32)),
+                            (b, s))
+    kpos = jnp.broadcast_to(jnp.atleast_2d(jnp.asarray(kpos, jnp.int32)),
+                            (b, t))
+    dsize, msize = mesh_axis_sizes(mesh)
+    bax = "data" if dsize > 1 and b % dsize == 0 else None
+    hax = "model" if msize > 1 and hkv % msize == 0 else None
+    heads = jax.sharding.PartitionSpec(bax, None, hax, None)
+    pos = jax.sharding.PartitionSpec(bax, None)
+    out = jax.shard_map(call, mesh=mesh,
+                        in_specs=(heads, heads, heads, pos, pos),
+                        out_specs=heads, check_vma=False)(q, k, v, qpos, kpos)
     return out.astype(v.dtype)
 
 

@@ -245,13 +245,11 @@ def profiler_annotations_enabled() -> bool:
 
 
 def profile_scope(name: str, **kwargs):
-    """``jax.profiler.TraceAnnotation(name)`` when annotations are enabled
-    (and jax is importable); a free null context otherwise — safe to wrap
-    hot dispatch sites unconditionally."""
+    """``jax.profiler.TraceAnnotation(name)`` when annotations are enabled;
+    a free null context otherwise — safe to wrap hot dispatch sites
+    unconditionally."""
     if not _PROFILER_ANNOTATIONS:
         return contextlib.nullcontext()
-    try:
-        from jax.profiler import TraceAnnotation
-    except Exception:  # pragma: no cover - jax always present in this repo
-        return contextlib.nullcontext()
+    from jax.profiler import TraceAnnotation
+
     return TraceAnnotation(name, **kwargs)

@@ -6,7 +6,7 @@ Every execution surface (``kan_network_apply(quantized=True)``,
 ``backend=`` strings and ``default_interpret()`` probes.  Three registered
 backends run the same deployed bundle (duck-typed: ``.dims``, ``.specs``,
 ``.layers`` (padded {"lut","wc","wb"}, or the int4-packed
-{"lut"[,"lutp"],"wcp","wscale","wb"} form for <=4-bit layers),
+{"lut","wcp","wscale","wb"} form for <=4-bit layers),
 ``.residual_raw``):
 
   * ``"ref"``    — the layered jnp composition (moved here from
@@ -56,6 +56,7 @@ from ..obs.trace import profile_scope
 from ..core.asp_quant import dense_basis_from_codes, quantize_input
 from ..core.cim import CIMConfig
 from ..core.tmdv import TMDVConfig, apply_input_noise
+from ..kernels import default_interpret
 from ..kernels.kan_spline.pipeline import (
     kan_pipeline_impl,
     run_pipeline_layer,
@@ -109,11 +110,6 @@ def dispatch_counts() -> dict:
 
 def reset_dispatch_counts() -> None:
     DISPATCH_COUNTS.clear()
-
-
-def default_interpret() -> bool:
-    """Pallas kernels need interpret mode off-TPU (CPU containers, CI)."""
-    return jax.default_backend() != "tpu"
 
 
 # ----------------------------------------------------------------------------

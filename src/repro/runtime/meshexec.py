@@ -39,26 +39,14 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # moved to the jax namespace in newer releases
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 
 def _shard_map_norep(body, *, mesh, in_specs, out_specs):
-    """shard_map without replication checking, across jax API renames.
+    """shard_map without replication checking: the checker cannot prove
+    replication through pallas_call (it has no replication rule)."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
-    The rep checker cannot prove replication through pallas_call (no rep
-    rule), so it must be off; the kwarg is ``check_rep`` on older jax and
-    ``check_vma`` on releases where shard_map lives in the jax namespace.
-    """
-    for kw in ({"check_rep": False}, {"check_vma": False}, {}):
-        try:
-            return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
-        except TypeError:
-            continue
-    raise TypeError("no compatible shard_map signature found")
 
 __all__ = [
     "ShardContext",

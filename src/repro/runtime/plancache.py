@@ -108,6 +108,11 @@ class PlanCache:
                 self._entries.popitem(last=False)
             return entry
 
+    def keys(self) -> list:
+        """The PlanKeys of the cached compiled applies, oldest first."""
+        with self._lock:
+            return list(self._entries)
+
     def record_trace(self) -> None:
         """Called from inside a jitted apply body: one real (re)trace."""
         self.traces += 1

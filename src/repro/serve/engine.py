@@ -617,6 +617,33 @@ class ServeEngine:
         s["slot_blocks"] = [len(b) for b in self._slot_blocks]
         return s
 
+    def teacher_forced_logits(self, prompt: list, tokens: list) -> np.ndarray:
+        """Logits of ``prompt`` followed by ``tokens``, fed one decode step
+        at a time through this engine's own prefill and decode steps
+        (chunking, paging, backends and mesh as configured).
+
+        Returns (1 + len(tokens), V) f32: row 0 is the prefill's last-token
+        logits, row i+1 the decode logits after consuming ``tokens[i]``.
+        Borrows the lowest free slot and releases it before returning —
+        how two engine configurations are compared logit for logit on the
+        same token stream.
+        """
+        slot = self._free_slot()
+        if slot is None:
+            raise RuntimeError("teacher_forced_logits needs a free slot")
+        req = Request(rid=-1, prompt=list(prompt),
+                      max_new_tokens=len(tokens) + 1)
+        try:
+            rows = [np.asarray(self._prefill_slot(slot, req))]
+            step = np.zeros(self.slots, np.int32)
+            for tok in tokens:
+                step[slot] = tok
+                rows.append(np.asarray(self.decode_active(step)[slot]))
+                self.pos[slot] += 1
+        finally:
+            self.release_slot(slot)
+        return np.stack(rows).astype(np.float32)
+
     # -- main loop --------------------------------------------------------
 
     def run(self, requests: list, log: Callable | None = None):

@@ -61,11 +61,13 @@ def test_prefill_then_decode_matches_forward(name):
 
 
 def test_serve_engine_kan_ffn_fused_path_matches_float_tokens():
-    """--kan-ffn serving regression: a small greedy batch decodes the SAME
-    tokens whether the KAN-FFN blocks run on the float path or are
-    ASP-quantized and executed through the fused Pallas pipeline
-    (kan_deploy=True, interpret mode on CPU).  int8 + SH-LUT error is far
-    below the greedy argmax margin on this config."""
+    """--kan-ffn serving regression: along the float engine's greedy token
+    streams, the logits of the ASP-quantized KAN-FFN path (kan_deploy=True,
+    the fused Pallas pipeline — interpret mode on CPU) stay within the
+    quantization error of the float path's logits, prefill and every decode
+    step.  Tokens are not compared: on random weights an argmax can sit
+    inside that error."""
+    from repro.runtime.parity import assert_close
     from repro.serve.engine import Request, ServeEngine
 
     cfg = smoke_config("qwen2.5-14b").kan_variant()
@@ -82,13 +84,19 @@ def test_serve_engine_kan_ffn_fused_path_matches_float_tokens():
         return reqs
 
     float_engine = ServeEngine(params, cfg, slots=2, max_len=32)
-    float_out = {r.rid: r.output for r in float_engine.run(make_reqs())}
-
+    served = float_engine.run(make_reqs())
     fused_engine = ServeEngine(params, cfg, slots=2, max_len=32,
                                kan_deploy=True)
-    fused_out = {r.rid: r.output for r in fused_engine.run(make_reqs())}
-
-    assert fused_out == float_out
+    for r in served:
+        assert r.status == "done" and len(r.output) == 4
+        want = float_engine.teacher_forced_logits(r.prompt, r.output[:-1])
+        got = fused_engine.teacher_forced_logits(r.prompt, r.output[:-1])
+        # 8-bit ASP activation codes and int8 per-channel weights each
+        # carry up to half an LSB of 2**-7 relative error per term, which
+        # reaches the logits as ~1% of their scale (measured 0.7-1.0%);
+        # dropping the FFN altogether moves them by ~58%.
+        assert_close(f"request {r.rid} logits", got, want, 3e-2,
+                     "8-bit ASP quantization error")
 
 
 def test_serve_engine_kan_deploy_rejects_non_kan_config():
