@@ -1,0 +1,3 @@
+"""idle_share.batch: see harness/readers.py, idle_share."""
+
+from harness.readers import idle_share as read  # noqa: F401
