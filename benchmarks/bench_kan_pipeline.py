@@ -270,8 +270,6 @@ def _bench_sustained(requests: int, max_new: int, print_fn=print,
             "itl_p95_s": s["itl_s"]["p95"],
             "queue_depth_max": s["queue_depth"]["max"],
             "queue_depth_mean": s["queue_depth"]["mean"],
-            "queue_depth_trace": [[round(ts, 4), d]
-                                  for ts, d in sched.queue_depth_trace()],
             "prefix_hit_rate": None if kv is None else kv["prefix_hit_rate"],
             "prefix_hits": None if kv is None else kv["prefix_hits"],
             "prefix_misses": None if kv is None else kv["prefix_misses"],

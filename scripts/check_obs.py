@@ -16,7 +16,13 @@ Takes the files a served workload wrote (`launch.serve --metrics-dump` /
     to earlier spans, every span closed (t1 >= t0), and each traced
     request carrying the full documented taxonomy: a ``request`` span with
     ``queued`` child, a terminal status, and — for served requests — a
-    ``first_token`` event between ``prefill`` and ``decode``.
+    ``first_token`` event between ``prefill`` and ``decode``.  Records
+    with no ``rid`` are the scheduler's lexical spans: ``serve.*`` spans
+    nested inside their parent's interval, under a ``serve.round`` root
+    whose counters (``chunks``, ``chunk_tokens``, ``decode_slots``,
+    ``admitted``, ``queued``) are whole numbers, with ``chunks`` equal to
+    the prefill dispatches below it and ``decode_slots`` > 0 exactly when a
+    decode or verify step ran in it.
 
     PYTHONPATH=src python scripts/check_obs.py \
         --prom metrics.prom --json metrics.json --trace trace.jsonl
@@ -85,6 +91,56 @@ def check_json(path: str) -> list:
 
 SPAN_KEYS = {"type", "name", "id", "parent", "rid", "t0", "t1", "attrs"}
 EVENT_KEYS = {"type", "name", "id", "parent", "rid", "t0", "attrs"}
+ROUND_COUNTERS = ("chunks", "chunk_tokens", "decode_slots", "admitted",
+                  "queued")
+PREFILL_DISPATCHES = ("serve.prefill_chunk", "serve.prefill")
+STEP_SPANS = ("serve.decode_step", "serve.verify")
+
+
+def check_lexical(path: str, spans: dict) -> list:
+    """The rid-less spans: serve.* spans nested in their parent's interval
+    under a serve.round root whose counters match its children."""
+    errors = []
+    below: dict = {}   # round id -> names of the spans under it
+    for sid, rec in spans.items():
+        if rec["rid"] is not None:
+            continue
+        where = f"{path}: span {rec['name']}#{sid}"
+        if not rec["name"].startswith("serve."):
+            errors.append(f"{where}: a span with no rid must be serve.*")
+            continue
+        root, par = rec, spans.get(rec["parent"])
+        if par is not None:
+            if par["rid"] is not None or not par["name"].startswith("serve."):
+                errors.append(f"{where}: parent is not a serve.* span")
+                continue
+            if rec["t0"] < par["t0"] or rec["t1"] > par["t1"]:
+                errors.append(f"{where}: outside its parent's interval")
+        while root["parent"] is not None and root["parent"] in spans:
+            root = spans[root["parent"]]
+        if root["name"] != "serve.round":
+            errors.append(f"{where}: not under a serve.round")
+        elif root is not rec:
+            below.setdefault(root["id"], []).append(rec["name"])
+    for sid, rec in spans.items():
+        if rec["rid"] is not None or rec["name"] != "serve.round":
+            continue
+        where = f"{path}: serve.round#{sid}"
+        a = rec["attrs"]
+        if not all(isinstance(a.get(k), int) and a[k] >= 0
+                   for k in ROUND_COUNTERS):
+            errors.append(f"{where}: counters {a} not whole numbers "
+                          f"for each of {ROUND_COUNTERS}")
+            continue
+        names = below.get(sid, [])
+        if a["chunks"] != sum(n in PREFILL_DISPATCHES for n in names):
+            errors.append(f"{where}: chunks={a['chunks']} but "
+                          f"{sum(n in PREFILL_DISPATCHES for n in names)} "
+                          f"prefill dispatches below it")
+        if (a["decode_slots"] > 0) != any(n in STEP_SPANS for n in names):
+            errors.append(f"{where}: decode_slots={a['decode_slots']} "
+                          f"disagrees with its decode/verify steps")
+    return errors
 
 
 def check_trace(path: str) -> list:
@@ -121,6 +177,7 @@ def check_trace(path: str) -> list:
                 rec["name"], []).append(rec)
     if not by_rid:
         errors.append(f"{path}: no per-request records at all")
+    errors += check_lexical(path, spans)
     for rid, names in sorted(by_rid.items()):
         if "request" not in names or "queued" not in names:
             errors.append(f"{path}: rid {rid}: missing request/queued span")
@@ -133,7 +190,10 @@ def check_trace(path: str) -> list:
                 if name not in names:
                     errors.append(f"{path}: rid {rid}: served request "
                                   f"missing {name}")
-    print(f"{path}: {last_id + 1} records, {len(by_rid)} requests ok")
+    rounds = sum(r["rid"] is None and r["name"] == "serve.round"
+                 for r in spans.values())
+    print(f"{path}: {last_id + 1} records, {len(by_rid)} requests, "
+          f"{rounds} rounds ok")
     return errors
 
 

@@ -32,6 +32,10 @@
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-14b \
         --metrics-dump metrics.prom --metrics-dump metrics.json \
         --trace-out trace.jsonl --stats-interval 1.0
+    # a jax.profiler capture of the run, and beside it the request and
+    # round spans on the profiler's clock (open both in Perfetto):
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-14b \
+        --kv-block-size 16 --prefill-chunk 8 --profile-dir prof/
 """
 
 from __future__ import annotations
@@ -171,6 +175,13 @@ def main():
         help="record one span tree per request and export it at shutdown: "
              "'.json' suffix -> Chrome trace-event JSON (chrome://tracing), "
              "anything else (e.g. '.jsonl') -> JSONL span records",
+    )
+    ap.add_argument(
+        "--profile-dir", default=None, metavar="DIR",
+        help="capture a jax.profiler trace of the served run into DIR with "
+             "profiler annotations on, and write the scheduler's spans "
+             "beside the capture's .xplane.pb (spans.trace.json, Chrome "
+             "trace-event JSON) shifted onto the profiler's clock",
     )
     ap.add_argument(
         "--log-level", default=None,
@@ -314,7 +325,8 @@ def main():
 
     sched = Scheduler(engine, max_queue=args.queue_limit,
                       log=None if args.stream else print,
-                      trace=args.trace_out is not None,
+                      trace=args.trace_out is not None
+                      or args.profile_dir is not None,
                       stats_interval_s=args.stats_interval)
     on_token = None
     if args.stream:
@@ -326,9 +338,15 @@ def main():
         except QueueFull as e:
             dropped += 1
             log.warning("backpressure", detail=str(e))
+    if args.profile_dir:
+        obs.enable_profiler_annotations()
+        jax.profiler.start_trace(args.profile_dir, create_perfetto_trace=True)
     t0 = time.perf_counter()
     results = sched.run_until_idle()
     wall = time.perf_counter() - t0
+    if args.profile_dir:
+        jax.profiler.stop_trace()
+        write_profiler_spans(sched.tracer, args.profile_dir, log)
     served = [r for r in results if r.status == "done"]
     total = sum(len(r.output) for r in served)
     stats = engine.compile_stats()
@@ -351,7 +369,7 @@ def main():
              tokens_per_s=round(s["tokens_per_s"] or 0.0, 1))
     log.info("queue depth", max=s["queue_depth"]["max"],
              mean=round(s["queue_depth"]["mean"], 2),
-             samples=s["queue_depth"]["samples"])
+             rounds=s["queue_depth"]["rounds"])
     if s["kv"] is not None:
         kv = s["kv"]
         log.info("kv pool", hit_rate=round(kv["prefix_hit_rate"], 2),
@@ -389,6 +407,25 @@ def main():
         log.info("metrics dump written", path=path)
     if metrics_server is not None:
         metrics_server.shutdown()
+
+
+def write_profiler_spans(tracer, profile_dir: str, log) -> None:
+    """Fit the tracer's clock to the capture under ``profile_dir`` and
+    write its spans there, shifted onto the profiler's clock."""
+    import glob
+
+    paths = sorted(glob.glob(os.path.join(profile_dir, "**", "*.xplane.pb"),
+                             recursive=True), key=os.path.getmtime)
+    if not paths:
+        raise SystemExit(f"no .xplane.pb under {profile_dir}")
+    fit = tracer.profiler_offset(obs.anchored_events(paths[-1]))
+    if fit is None:
+        raise SystemExit(f"{paths[-1]}: no span of this run is anchored")
+    out = os.path.join(os.path.dirname(paths[-1]), "spans.trace.json")
+    tracer.export_chrome(out, offset_s=fit.offset_s)
+    log.info("profiler spans written", path=out, anchored=fit.n,
+             offset_s=round(fit.offset_s, 9),
+             spread_us=round(fit.spread_s * 1e6, 1))
 
 
 if __name__ == "__main__":

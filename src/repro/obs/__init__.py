@@ -11,10 +11,11 @@ shared schema and one way out of the process:
     ``runtime.backend_dispatch{backend=...}``), default-off and zero-cost
     when off; existing counter owners feed it through snapshot-time
     collectors.
-  * :mod:`repro.obs.trace` — per-request span timelines recorded on the
-    scheduler's injectable clock (deterministic under ``ManualClock``),
-    exported as JSONL or Chrome-trace JSON, plus ``jax.profiler``
-    annotation scopes for kernel dispatch sites.
+  * :mod:`repro.obs.trace` — per-request span timelines and lexical
+    ``profile_scope`` spans (scheduler rounds, engine steps) recorded on
+    the scheduler's injectable clock (deterministic under ``ManualClock``),
+    mirrored as ``jax.profiler`` annotations anchored to that clock, and
+    exported as JSONL or Chrome-trace JSON.
   * :mod:`repro.obs.logging` — one leveled structured logger
     (``REPRO_LOG_LEVEL``) replacing the ad-hoc ``log=`` lambdas, with the
     bare-callable back-compat path preserved.
@@ -57,15 +58,20 @@ from .metrics import (
     enabled,
 )
 from .trace import (
+    ClockFit,
+    ProfileEvent,
     Span,
     Tracer,
+    anchored_events,
     disable_profiler_annotations,
     enable_profiler_annotations,
     profile_scope,
     profiler_annotations_enabled,
+    recording,
 )
 
 __all__ = [
+    "ClockFit",
     "DEFAULT_TIME_EDGES_S",
     "ENV_LOG_LEVEL_VAR",
     "ENV_OBS_VAR",
@@ -76,8 +82,10 @@ __all__ = [
     "Histogram",
     "Logger",
     "MetricsRegistry",
+    "ProfileEvent",
     "Span",
     "Tracer",
+    "anchored_events",
     "as_logger",
     "disable",
     "disable_profiler_annotations",
@@ -90,5 +98,6 @@ __all__ = [
     "profile_scope",
     "profiler_annotations_enabled",
     "prometheus_text",
+    "recording",
     "start_metrics_server",
 ]

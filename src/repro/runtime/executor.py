@@ -290,7 +290,8 @@ class _CachedExecutor:
         )
         _, apply = PLAN_CACHE.get(plan_key, self._build)
         DISPATCH_COUNTS[self.name] += 1
-        with profile_scope(f"kan_spline.{self.name}"):
+        # annotation-only: on the LM path this fires while the step traces
+        with profile_scope(f"kan_spline.{self.name}", record=False):
             out = self._run(apply, _pad_batch(codes, bucket),
                             _pad_batch(xraw, bucket), dep.layers, key,
                             return_intermediates)

@@ -476,7 +476,8 @@ class ServeEngine:
             return pool.at[:, slot].set(one)
 
         self.cache = jax.tree.map(splice, self.cache, cache1)
-        return np.asarray(logits[0])
+        with profile_scope("serve.readback"):
+            return np.asarray(logits[0])
 
     def _prefill_paged_chunk(self, slot: int, st: dict):
         """One chunk of paged prefill; returns final logits or None."""
@@ -495,26 +496,28 @@ class ServeEngine:
         bs = self.kv_block_size
         blocks = self._slot_blocks[slot]
         need = -(-(start + take) // bs)          # ceil: blocks covering chunk
-        try:
-            while len(blocks) < need:
-                bid = self.pool.alloc()
-                self.block_tables[slot, len(blocks)] = bid
-                blocks.append(bid)
-        except Exception:
-            self.release_slot(slot)
-            raise
-        chunk = req.prompt[start:start + take] + [0] * (c - take)
-        tokens = jnp.asarray([chunk], jnp.int32)
-        table = jnp.asarray(self.block_tables[slot])
-        with runtime.use_backend(self.kan_backend), \
-                runtime.use_mesh(self.mesh), \
-                profile_scope("serve.prefill_chunk"):
-            logits, self.cache = self._prefill_chunk_fn(
-                self.params, self.cache, tokens, table,
-                jnp.asarray(start, jnp.int32),
-                jnp.asarray(start + take, jnp.int32),
-                jnp.asarray(plen - 1, jnp.int32),
-            )
+        # the span holds the chunk's host work: block allocation, its
+        # arguments and the dispatch
+        with profile_scope("serve.prefill_chunk"):
+            try:
+                while len(blocks) < need:
+                    bid = self.pool.alloc()
+                    self.block_tables[slot, len(blocks)] = bid
+                    blocks.append(bid)
+            except Exception:
+                self.release_slot(slot)
+                raise
+            chunk = req.prompt[start:start + take] + [0] * (c - take)
+            tokens = jnp.asarray([chunk], jnp.int32)
+            table = jnp.asarray(self.block_tables[slot])
+            with runtime.use_backend(self.kan_backend), \
+                    runtime.use_mesh(self.mesh):
+                logits, self.cache = self._prefill_chunk_fn(
+                    self.params, self.cache, tokens, table,
+                    jnp.asarray(start, jnp.int32),
+                    jnp.asarray(start + take, jnp.int32),
+                    jnp.asarray(plen - 1, jnp.int32),
+                )
         st["next"] = start + take
         if st["next"] < plen:
             return None
@@ -522,7 +525,8 @@ class ServeEngine:
         # prefix blocks re-publish as no-ops); partial tail blocks — which
         # decode will keep writing — are never shared
         self.pool.publish_prefix(req.prompt, blocks[:plen // bs])
-        return np.asarray(logits[0])
+        with profile_scope("serve.readback"):
+            return np.asarray(logits[0])
 
     def _ensure_decode_blocks(self, horizon: int = 1) -> None:
         """Allocate the pool blocks covering each active slot's next
@@ -546,25 +550,26 @@ class ServeEngine:
         """One pooled decode step over all slots; returns device logits
         (slots, V) and updates the cache in place.  ``pos`` bookkeeping is
         the caller's (the scheduler advances it after selecting tokens)."""
-        args = ()
-        if self.paged:
-            self._ensure_decode_blocks()
-            tables = self.block_tables
-            if self._prefilling:
-                # mid-prefill slots ride the pooled step with a stale pos;
-                # point their rows at the scratch block so the step's KV
-                # write can't corrupt the blocks their prefill is filling
-                tables = tables.copy()
-                for s in self._prefilling:
-                    tables[s] = 0
-            args = (jnp.asarray(tables),)
-        with runtime.use_backend(self.kan_backend), \
-                runtime.use_mesh(self.mesh), \
-                profile_scope("serve.decode_step"):
-            logits, self.cache = self._decode(
-                self.params, self.cache, jnp.asarray(tokens, jnp.int32),
-                jnp.asarray(self.pos), *args,
-            )
+        with profile_scope("serve.decode_step"):
+            args = ()
+            if self.paged:
+                self._ensure_decode_blocks()
+                tables = self.block_tables
+                if self._prefilling:
+                    # mid-prefill slots ride the pooled step with a stale
+                    # pos; point their rows at the scratch block so the
+                    # step's KV write can't corrupt the blocks their
+                    # prefill is filling
+                    tables = tables.copy()
+                    for s in self._prefilling:
+                        tables[s] = 0
+                args = (jnp.asarray(tables),)
+            with runtime.use_backend(self.kan_backend), \
+                    runtime.use_mesh(self.mesh):
+                logits, self.cache = self._decode(
+                    self.params, self.cache, jnp.asarray(tokens, jnp.int32),
+                    jnp.asarray(self.pos), *args,
+                )
         return logits
 
     def verify_active(self, tokens) -> jax.Array:
@@ -580,21 +585,21 @@ class ServeEngine:
         :meth:`truncate_slot`.  ``pos`` bookkeeping is the caller's, same
         as ``decode_active``."""
         s = int(tokens.shape[1])
-        self._ensure_decode_blocks(horizon=s)
-        tables = self.block_tables
-        if self._prefilling:
-            # mid-prefill slots ride along with a stale pos; scratch-redirect
-            # their rows exactly as decode_active does
-            tables = tables.copy()
-            for sl in self._prefilling:
-                tables[sl] = 0
-        with runtime.use_backend(self.kan_backend), \
-                runtime.use_mesh(self.mesh), \
-                profile_scope("serve.verify"):
-            logits, self.cache = self._verify(
-                self.params, self.cache, jnp.asarray(tokens, jnp.int32),
-                jnp.asarray(self.pos), jnp.asarray(tables),
-            )
+        with profile_scope("serve.verify"):
+            self._ensure_decode_blocks(horizon=s)
+            tables = self.block_tables
+            if self._prefilling:
+                # mid-prefill slots ride along with a stale pos;
+                # scratch-redirect their rows exactly as decode_active does
+                tables = tables.copy()
+                for sl in self._prefilling:
+                    tables[sl] = 0
+            with runtime.use_backend(self.kan_backend), \
+                    runtime.use_mesh(self.mesh):
+                logits, self.cache = self._verify(
+                    self.params, self.cache, jnp.asarray(tokens, jnp.int32),
+                    jnp.asarray(self.pos), jnp.asarray(tables),
+                )
         return logits
 
     def truncate_slot(self, slot: int, new_len: int) -> None:

@@ -26,17 +26,18 @@ event-driven loop over a request queue:
     streams to ``ServeEngine.run()`` — ``run()`` is in fact a thin
     synchronous driver over this scheduler;
   * **metrics** — per-request TTFT and inter-token latencies plus
-    aggregate tokens/s, queue-depth-over-time samples and admission
-    counters, snapshotted by :meth:`Scheduler.stats` (see
-    ``docs/serving.md`` for the metrics glossary).  With the process-wide
-    obs registry enabled (``repro.obs.enable()`` / ``REPRO_OBS=1``) the
-    same events also feed the documented dotted series (``serve.ttft_s``,
-    ``serve.completed``, ``kv.blocks_in_use``, ...; see
+    aggregate tokens/s, queue-depth max/mean and admission counters,
+    snapshotted by :meth:`Scheduler.stats` (see ``docs/serving.md`` for
+    the metrics glossary).  With the process-wide obs registry enabled
+    (``repro.obs.enable()`` / ``REPRO_OBS=1``) the same events also feed
+    the documented dotted series (``serve.ttft_s``, ``serve.completed``,
+    ``kv.blocks_in_use``, ...; see
     ``docs/observability.md``) — recording only, token streams are
     bit-identical with observability on or off;
   * **tracing** — ``Scheduler(trace=True)`` records one span tree per
     request (``request`` > ``queued`` / ``prefill`` / ``decode`` +
-    ``first_token`` events) on the scheduler's own clock via
+    ``first_token`` events) and one ``serve.round`` span per working
+    round (see :meth:`Scheduler.step`) on the scheduler's own clock via
     :class:`repro.obs.Tracer` (``sched.tracer``), so a ``ManualClock``
     workload exports a byte-identical JSONL timeline run to run;
   * **logging** — ``log=`` accepts the legacy bare callable (every line
@@ -241,8 +242,6 @@ class Scheduler:
         self._verify_s: collections.deque = collections.deque(maxlen=4096)
         self._span_start: float | None = None  # first admission
         self._span_end: float | None = None    # last emitted token
-        self._depth_samples: collections.deque = collections.deque(
-            maxlen=4096)                       # (elapsed_s, depth) trace tail
         self._depth_rounds = 0
         self._depth_sum = 0
         self._depth_max = 0
@@ -342,13 +341,34 @@ class Scheduler:
         Returns True if any progress was made (a prefill or a decode ran);
         False means the scheduler is idle right now — either fully drained,
         or every queued request has a future arrival time.
+
+        Traced (``trace=True``), or with profiler annotations on, each
+        round is one ``serve.round`` span.  Its children: ``serve.admit``
+        (expiry, admission, prefix lookup), ``serve.prefill_chunk`` /
+        ``serve.decode_step`` (the engine's steps), ``serve.readback``
+        (the host's waits for device results) and ``serve.emit`` (token
+        selection, ``on_token``, retiring finished slots).  Its counters:
+        ``chunks`` and ``chunk_tokens`` (prefill chunks dispatched and
+        their prompt tokens), ``decode_slots`` (slots in the decode or
+        verify step, 0 if none), ``admitted``, and ``queued`` (queue depth
+        after admission).  A round that found nothing to do reads 0 in
+        ``chunks``, ``decode_slots`` and ``admitted``.
         """
         now = self.elapsed()
-        self._expire(now)
-        progressed = self._admit_arrived(now)
-        progressed = self._advance_prefills() or progressed
+        if self.tracer is None and not obs.trace.scopes_on():
+            return self._round(now, None)
+        with obs.recording(self.tracer), \
+                obs.profile_scope("serve.round") as rnd:
+            if rnd is not None:
+                rnd.attrs.update(chunks=0, chunk_tokens=0)
+            return self._round(now, rnd)
+
+    def _round(self, now: float, rnd) -> bool:
+        with obs.profile_scope("serve.admit"):
+            self._expire(now)
+            admitted = self._admit_arrived(now, rnd)
+        progressed = self._advance_prefills(rnd) or admitted > 0
         depth = len(self.queue)
-        self._depth_samples.append((now, depth))
         self._depth_rounds += 1
         self._depth_sum += depth
         self._depth_max = max(self._depth_max, depth)
@@ -358,6 +378,9 @@ class Scheduler:
                 and now - self._last_stats_line >= self.stats_interval_s):
             self._last_stats_line = now
             self._stats_line(now)
+        if rnd is not None:
+            rnd.attrs.update(admitted=admitted, queued=depth, decode_slots=sum(
+                r is not None for r in self.engine.active))
         if any(r is not None for r in self.engine.active):
             if self.engine.spec_k:
                 self._spec_round()
@@ -403,9 +426,10 @@ class Scheduler:
                 keep.append(r)
         self.queue = keep
 
-    def _admit_arrived(self, now: float) -> bool:
+    def _admit_arrived(self, now: float, rnd) -> int:
+        """Admit arrived requests into free slots; returns how many."""
         eng = self.engine
-        admitted = False
+        admitted = 0
         while True:
             slot = eng._free_slot()
             if slot is None:
@@ -424,26 +448,42 @@ class Scheduler:
                 self._trace_admit(req, chunked=True)
                 eng._begin_prefill(slot, req)
                 req.status = "running"
-                admitted = True
+                admitted += 1
                 self.log.debug("admitted request", rid=req.rid,
                                prefill="chunked", queued=len(self.queue))
                 continue
             self._trace_admit(req, chunked=False)
-            logits = eng._prefill_slot(slot, req)
+            eng._begin_prefill(slot, req)
+            logits = None
+            while logits is None:
+                logits = self._prefill_chunk(slot, rnd)
             self._first_token(req, logits)
-            admitted = True
+            admitted += 1
             self.log.debug("admitted request", rid=req.rid,
                            queued=len(self.queue))
         return admitted
 
-    def _advance_prefills(self) -> bool:
+    def _prefill_chunk(self, slot: int, rnd):
+        """One engine prefill step of ``slot``, counted on the round span
+        ``rnd`` (when traced); returns the first-token logits or None."""
+        st = self.engine._prefilling[slot]
+        start = st["next"]
+        logits = self.engine._prefill_step(slot)
+        if rnd is not None:
+            # a completed prompt leaves the engine's staging: count to its end
+            stop = st["next"] if logits is None else len(st["req"].prompt)
+            rnd.attrs["chunks"] += 1
+            rnd.attrs["chunk_tokens"] += stop - start
+        return logits
+
+    def _advance_prefills(self, rnd) -> bool:
         """One chunk of progress for every mid-prefill slot (chunked mode);
         emits the first token of any prompt that completes this round."""
         eng = self.engine
         progressed = False
         for slot in eng.prefilling_slots():
             req = eng._prefilling[slot]["req"]
-            logits = eng._prefill_step(slot)
+            logits = self._prefill_chunk(slot, rnd)
             progressed = True
             if self.tracer is not None:
                 sp = self._spans.get(req.rid)
@@ -456,31 +496,32 @@ class Scheduler:
 
     def _first_token(self, req, logits) -> None:
         """Select and record a freshly-prefilled request's first token."""
-        t = self.elapsed()
-        tok = self._select(req, logits)
-        req.output.append(tok)
-        req.status = "running"
-        req.ttft_s = t - req.arrival_s
-        self._ttfts.append(req.ttft_s)
-        if self._mx is not None:
-            self._mx["ttft"].observe(req.ttft_s)
-        if self.tracer is not None:
-            sp = self._spans.get(req.rid)
-            if sp is not None:
-                pre = sp.pop("prefill", None)
-                if pre is not None:
-                    self.tracer.end(pre, chunks=sp.pop("chunks", 0))
-                self.tracer.event("first_token", parent=sp["request"],
-                                  ttft_s=round(req.ttft_s, 9))
-                sp["decode"] = self.tracer.begin("decode",
-                                                 parent=sp["request"])
-        self._rec[req.rid] = {
-            "arrival": req.arrival_s, "admit": t, "token_times": [t],
-        }
-        if self._span_start is None or t < self._span_start:
-            self._span_start = t
-        self._span_end = t
-        self._emit(req, tok)
+        with obs.profile_scope("serve.emit"):
+            t = self.elapsed()
+            tok = self._select(req, logits)
+            req.output.append(tok)
+            req.status = "running"
+            req.ttft_s = t - req.arrival_s
+            self._ttfts.append(req.ttft_s)
+            if self._mx is not None:
+                self._mx["ttft"].observe(req.ttft_s)
+            if self.tracer is not None:
+                sp = self._spans.get(req.rid)
+                if sp is not None:
+                    pre = sp.pop("prefill", None)
+                    if pre is not None:
+                        self.tracer.end(pre, chunks=sp.pop("chunks", 0))
+                    self.tracer.event("first_token", parent=sp["request"],
+                                      ttft_s=round(req.ttft_s, 9))
+                    sp["decode"] = self.tracer.begin("decode",
+                                                     parent=sp["request"])
+            self._rec[req.rid] = {
+                "arrival": req.arrival_s, "admit": t, "token_times": [t],
+            }
+            if self._span_start is None or t < self._span_start:
+                self._span_start = t
+            self._span_end = t
+            self._emit(req, tok)
 
     def _decode_round(self) -> None:
         eng = self.engine
@@ -496,13 +537,19 @@ class Scheduler:
         # device-side argmax — transferring B ints per step, not the whole
         # (slots, vocab) logits matrix; the full rows come to host only
         # when some active request actually samples
-        if any(getattr(r, "sampling", None) is not None
-               for r in eng.active if r is not None):
-            rows, nxt = np.asarray(logits), None
-        else:
-            rows, nxt = None, np.asarray(jnp.argmax(logits, axis=-1))
+        with obs.profile_scope("serve.readback"):
+            if any(getattr(r, "sampling", None) is not None
+                   for r in eng.active if r is not None):
+                rows, nxt = np.asarray(logits), None
+            else:
+                rows, nxt = None, np.asarray(jnp.argmax(logits, axis=-1))
         t = self.elapsed()
         self._span_end = t
+        with obs.profile_scope("serve.emit"):
+            self._emit_decoded(rows, nxt, t)
+
+    def _emit_decoded(self, rows, nxt, t: float) -> None:
+        eng = self.engine
         for i, r in enumerate(eng.active):
             if r is None:
                 continue
@@ -568,12 +615,14 @@ class Scheduler:
         # pure-greedy pools take the device-side argmax — (slots, k+1) ints
         # per round, not the logits cube; full rows come to host only when
         # some active request actually samples
-        if any(getattr(r, "sampling", None) is not None for _, r in active):
-            rows = np.asarray(logits)                       # (slots, k+1, V)
-            g = np.argmax(rows, axis=-1)
-        else:
-            rows = None
-            g = np.asarray(jnp.argmax(logits, axis=-1))     # (slots, k+1)
+        with obs.profile_scope("serve.readback"):
+            if any(getattr(r, "sampling", None) is not None
+                   for _, r in active):
+                rows = np.asarray(logits)                   # (slots, k+1, V)
+                g = np.argmax(rows, axis=-1)
+            else:
+                rows = None
+                g = np.asarray(jnp.argmax(logits, axis=-1))  # (slots, k+1)
         t2 = time.perf_counter()
         self._draft_s.append(t1 - t0)
         self._verify_s.append(t2 - t1)
@@ -582,6 +631,11 @@ class Scheduler:
             self._mx["spec_verify_s"].observe(t2 - t1)
         t = self.elapsed()
         self._span_end = t
+        with obs.profile_scope("serve.emit"):
+            self._emit_verified(active, tokens, rows, g, t)
+
+    def _emit_verified(self, active, tokens, rows, g, t: float) -> None:
+        eng, draft, k = self.engine, self.engine.draft, self.engine.spec_k
         for i, r in active:
             rec = self._rec.setdefault(
                 r.rid, {"arrival": r.arrival_s, "admit": t, "token_times": []}
@@ -755,11 +809,6 @@ class Scheduler:
 
     # -- observability ----------------------------------------------------
 
-    def queue_depth_trace(self) -> list:
-        """(elapsed_s, queue_depth) samples, one per scheduling round
-        (capped tail: the most recent 4096 rounds)."""
-        return list(self._depth_samples)
-
     def drain_finished(self) -> list:
         """Return and clear the finished list — long-lived callers should
         drain periodically so completed Request objects don't accumulate."""
@@ -830,7 +879,6 @@ class Scheduler:
                 "verify_s": _summary(list(self._verify_s)),
             }),
             "queue_depth": {
-                "samples": len(self._depth_samples),
                 "rounds": self._depth_rounds,
                 "max": self._depth_max,
                 "mean": (self._depth_sum / self._depth_rounds

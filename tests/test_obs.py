@@ -318,7 +318,186 @@ def test_chrome_export_shape(float_setup, tmp_path):
     doc = json.loads(path.read_text())
     evs = doc["traceEvents"]
     assert {e["ph"] for e in evs} <= {"X", "i"}
-    assert all(e["tid"] == 0 for e in evs)  # one timeline row per request
+    # one timeline row per request; the rounds' spans on the global row
+    assert all(e["tid"] == (-1 if e["name"].startswith("serve.") else 0)
+               for e in evs)
+    assert {e["tid"] for e in evs} == {-1, 0}
+
+
+# -- lexical spans: scheduler rounds on the profiler's clock -----------------
+
+
+class TickClock(ManualClock):
+    """Every reading moves time on by one tick, so spans have widths and a
+    run stays deterministic (the same reads give the same times)."""
+
+    def now(self) -> float:
+        self._t += 1e-3
+        return self._t
+
+
+ROUND_CHILDREN = {"serve.admit", "serve.prefill_chunk", "serve.readback",
+                  "serve.emit", "serve.decode_step"}
+ROUND_COUNTERS = {"chunks", "chunk_tokens", "decode_slots", "admitted",
+                  "queued"}
+
+
+def _serve_rounds(params, cfg, clock, path=None):
+    """Three 11-token prompts through 2 slots in chunks of 4, traced
+    (``clock`` None: the wall clock)."""
+    eng = ServeEngine(params, cfg, slots=2, max_len=32, kv_block_size=8,
+                      prefill_chunk=4)
+    sched = Scheduler(eng, clock=clock, trace=True)
+    for r in make_reqs(cfg, n=3, plen=11, max_new=3):
+        sched.submit(r)
+    sched.run_until_idle()
+    if path is not None:
+        sched.tracer.export_jsonl(path)
+    return sched
+
+
+def test_profile_scope_nests_counts_and_is_free_when_off():
+    clk = ManualClock()
+    tr = obs.Tracer(clock=clk.now)
+    obs.disable_profiler_annotations()
+    with obs.recording(tr):
+        with obs.profile_scope("serve.round", chunks=0) as rnd:
+            with obs.profile_scope("serve.admit", k=1) as adm:
+                clk.advance(0.5)
+                rnd.attrs["chunks"] += 2       # counted through the span
+            with obs.profile_scope("kan_spline.ref", record=False) as ann:
+                assert ann is None
+    assert (adm.parent, adm.attrs, adm.t1) == (rnd.id, {"k": 1}, 0.5)
+    assert rnd.attrs == {"chunks": 2}
+    assert [r["name"] for r in tr.records()] == ["serve.round", "serve.admit"]
+    # nothing recording, annotations off: one shared null context
+    assert obs.profile_scope("serve.round") is obs.profile_scope("x")
+    with obs.profile_scope("serve.round") as off:
+        assert off is None
+    assert len(tr.records()) == 2 and not obs.trace.scopes_on()
+
+
+def test_round_spans_tile_the_round_and_count_its_work(float_setup, tmp_path):
+    """A chunked-prefill workload: one serve.round per round, its children
+    in order inside it, and counters that add up to the work."""
+    cfg, params = float_setup
+    sched = _serve_rounds(params, cfg, TickClock(), tmp_path / "a.jsonl")
+    recs = sched.tracer.records()
+    spans = {r["id"]: r for r in recs if r["type"] == "span"}
+    rounds = [r for r in recs if r["name"] == "serve.round"]
+    assert rounds and all(r["parent"] is None and r["rid"] is None
+                          for r in rounds)
+    n_chunks = n_decode = 0
+    for rnd in rounds:
+        assert set(rnd["attrs"]) == ROUND_COUNTERS
+        kids = sorted((s for s in spans.values() if s["parent"] == rnd["id"]),
+                      key=lambda s: s["t0"])
+        assert {k["name"] for k in kids} <= ROUND_CHILDREN
+        assert kids[0]["name"] == "serve.admit"
+        # the children lie in the round, one after another; with the
+        # round's self time they tile it
+        edges = [rnd["t0"]] + [x for k in kids for x in (k["t0"], k["t1"])]
+        assert edges == sorted(edges) and kids[-1]["t1"] <= rnd["t1"]
+        busy = sum(k["t1"] - k["t0"] for k in kids)
+        assert (rnd["t1"] - rnd["t0"]) - busy > 0          # the self time
+        names = [k["name"] for k in kids]
+        assert rnd["attrs"]["chunks"] == names.count("serve.prefill_chunk")
+        assert (rnd["attrs"]["decode_slots"] > 0) == (
+            "serve.decode_step" in names)
+        n_chunks += rnd["attrs"]["chunks"]
+        n_decode += rnd["attrs"]["decode_slots"]
+    # per-round chunks add up to the chunks each request's prefill took
+    prefills = [r for r in recs if r["name"] == "prefill"]
+    assert n_chunks == sum(p["attrs"]["chunks"] for p in prefills) == 3 * 3
+    assert sum(r["attrs"]["chunk_tokens"] for r in rounds) == 3 * 11
+    assert sum(r["attrs"]["admitted"] for r in rounds) == 3
+    assert rounds[0]["attrs"]["queued"] == 1          # 3 requests, 2 slots
+    assert n_decode == sum(len(r.output) - 1 for r in sched.finished)
+    # and the export is byte-identical run to run
+    _serve_rounds(params, cfg, TickClock(), tmp_path / "b.jsonl")
+    a = (tmp_path / "a.jsonl").read_bytes()
+    b = (tmp_path / "b.jsonl").read_bytes()
+    assert a == b and b"serve.round" in a
+
+
+def test_idle_round_reads_no_work(float_setup):
+    """A round before the first arrival dispatches nothing: its span holds
+    only the admission scan, and its work counters read 0."""
+    cfg, params = float_setup
+    eng = ServeEngine(params, cfg, slots=2, max_len=32, kv_block_size=8,
+                      prefill_chunk=4)
+    sched = Scheduler(eng, clock=TickClock(), trace=True)
+    (req,) = make_reqs(cfg, n=1, plen=11, max_new=2)
+    req.arrival_s = 0.5
+    sched.submit(req)
+    assert sched.step() is False
+    recs = sched.tracer.records()
+    (rnd,) = [r for r in recs if r["name"] == "serve.round"]
+    assert rnd["attrs"] == {"chunks": 0, "chunk_tokens": 0,
+                            "decode_slots": 0, "admitted": 0, "queued": 1}
+    assert [r["name"] for r in recs if r["parent"] == rnd["id"]] == [
+        "serve.admit"]
+    sched.run_until_idle()
+    worked = [r for r in sched.tracer.records() if r["name"] == "serve.round"
+              and (r["attrs"]["chunks"] or r["attrs"]["decode_slots"])]
+    assert sum(r["attrs"]["chunk_tokens"] for r in worked) == 11
+
+
+def test_check_obs_accepts_round_spans_and_refuses_bad_counters(
+        float_setup, tmp_path):
+    import importlib.util
+    import pathlib
+
+    spec = importlib.util.spec_from_file_location(
+        "check_obs", pathlib.Path(__file__).parents[1] / "scripts"
+        / "check_obs.py")
+    check = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(check)
+    cfg, params = float_setup
+    path = tmp_path / "t.jsonl"
+    _serve_rounds(params, cfg, TickClock(), path)
+    assert check.check_trace(str(path)) == []
+    lines = [json.loads(x) for x in path.read_text().splitlines()]
+    for bad, want in ((lambda d: d["attrs"].update(chunks=99), "chunks=99"),
+                      (lambda d: d["attrs"].pop("queued"), "counters"),
+                      (lambda d: d.update(name="round"), "must be serve.")):
+        out = [dict(d, attrs=dict(d["attrs"])) for d in lines]
+        bad(next(d for d in out if d["name"] == "serve.round"
+                 and d["attrs"]["chunks"]))
+        path.write_text("".join(json.dumps(d) + "\n" for d in out))
+        errors = check.check_trace(str(path))
+        assert any(want in e for e in errors), errors
+
+
+def test_profiler_offset_maps_anchored_spans_on_cpu(float_setup, tmp_path):
+    """Rounds traced on the wall clock under a CPU jax.profiler capture:
+    every lexical span is anchored, and the fitted offset puts each one
+    within 0.5 ms of its event; the offset export lands on the events."""
+    import glob
+
+    cfg, params = float_setup
+    _serve_rounds(params, cfg, None)           # compile outside the capture
+    obs.enable_profiler_annotations()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        sched = _serve_rounds(params, cfg, None)
+    finally:
+        jax.profiler.stop_trace()
+        obs.disable_profiler_annotations()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = obs.anchored_events(path)
+    lexical = {r["id"]: r for r in sched.tracer.records()
+               if r["type"] == "span" and r["rid"] is None}
+    fit = sched.tracer.profiler_offset(events)
+    assert fit.n == len(lexical) == len(events)
+    assert fit.spread_s < 0.5e-3
+    shifted = {r["id"]: r for r in sched.tracer.records(fit.offset_s)}
+    for e in events:
+        sid = int(e.stats["id"])
+        assert e.name == lexical[sid]["name"]
+        assert abs(e.start_ns * 1e-9 - shifted[sid]["t0"]) < 0.5e-3
+    assert "serve.round" in {e.name for e in events}
+    assert sched.tracer.profiler_offset([]) is None
 
 
 # -- end-to-end: bit-identity + metrics coverage ------------------------------

@@ -210,8 +210,7 @@ def test_future_arrivals_wait_and_stats_snapshot(float_setup):
     assert s["submitted"] == 2 and s["completed"] == 2
     assert s["tokens"] == len(r0.output) + len(r1.output)
     assert s["ttft_s"]["n"] == 2 and s["ttft_s"]["p95"] is not None
-    assert s["queue_depth"]["samples"] > 0
-    assert len(sched.queue_depth_trace()) == s["queue_depth"]["samples"]
+    assert s["queue_depth"]["rounds"] > 0
 
 
 def test_engine_admit_without_free_slot_raises(float_setup):
@@ -268,10 +267,9 @@ def test_long_lived_scheduler_memory_stays_bounded(float_setup):
     assert s["tokens"] == 2 * total
     # stat tails are capped deques — a long-lived scheduler's footprint
     # does not grow with total requests served
-    for tail in (sched._ttfts, sched._itls, sched._depth_samples):
+    for tail in (sched._ttfts, sched._itls):
         assert tail.maxlen == 4096 and len(tail) <= 4096
     assert s["ttft_s"]["n"] == min(total, 4096)
-    assert s["queue_depth"]["rounds"] >= s["queue_depth"]["samples"]
     # the paged pool drained clean: no leaked blocks, invariants hold
     assert eng.pool.blocks_in_use() == 0
     assert eng._free_slots == list(range(eng.slots))
@@ -322,3 +320,37 @@ def test_request_defaults_keep_old_call_sites_working():
     r = Request(rid=0, prompt=[1, 2, 3], max_new_tokens=4)
     assert r.arrival_s == 0.0 and r.deadline_s is None and r.sampling is None
     assert r.status == "pending" and r.ttft_s == 0.0
+
+
+def _serve_chunked(params, cfg, trace: bool):
+    eng = ServeEngine(params, cfg, slots=2, max_len=32, kv_block_size=8,
+                      prefill_chunk=4)
+    sched = Scheduler(eng, clock=ManualClock(), trace=trace)
+    for r in make_reqs(cfg, n=3, plen=11, max_new=4):
+        sched.submit(r)
+    sched.run_until_idle()
+    return {r.rid: r.output for r in sched.finished}, sched
+
+
+def test_untraced_round_records_nothing(float_setup, monkeypatch):
+    """Tracing and annotations off: a round opens no span, counts into no
+    tracer (not even one installed around it) and keeps no per-round
+    sample; greedy streams match a traced run token for token."""
+    from repro import obs
+    from repro.obs import trace as T
+
+    cfg, params = float_setup
+    obs.disable_profiler_annotations()
+    opened = []
+    monkeypatch.setattr(T, "_Scope", lambda *a: opened.append(a))
+    outer = obs.Tracer(clock=ManualClock().now)
+    with obs.recording(outer):
+        plain, sched = _serve_chunked(params, cfg, trace=False)
+    assert sched.tracer is None and outer.records() == [] and opened == []
+    assert T._RECORDING is None
+    assert not hasattr(sched, "_depth_samples")
+    assert set(sched.stats()["queue_depth"]) == {"rounds", "max", "mean"}
+    monkeypatch.undo()
+    traced, tsched = _serve_chunked(params, cfg, trace=True)
+    assert traced == plain
+    assert any(r["name"] == "serve.round" for r in tsched.tracer.records())
