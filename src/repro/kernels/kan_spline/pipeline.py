@@ -6,7 +6,10 @@ accelerator.  ``kernel.py`` covers one layer; this module chains layers so
 that activations stay **int codes** between layers instead of round-tripping
 through dequantized f32 in Python:
 
-  * each layer runs the same fused datapath as ``_kan_spline_kernel``;
+  * each layer runs the same fused datapath as ``_kan_spline_kernel``, on
+    the grid ``(Bp/bb, Fp/bf, Op/bo)``: the output tile is innermost, so
+    the banded basis of a ``(bb, bf)`` code tile is built once and reused
+    by every output tile of the layer (:attr:`LayerPlan.basis_reuse`);
   * the inter-layer boundary — tanh domain rescale followed by
     ``quantize_input`` re-coding (the TPU analogue of the paper's N:1 TMDV
     input generator feeding the next array) — is fused into the producing
@@ -104,6 +107,15 @@ class LayerPlan:
     def emit_codes(self) -> bool:
         return self.next_spec is not None
 
+    @property
+    def basis_reuse(self) -> int:
+        """Output tiles that share one banded basis tile (``op // bo``).
+
+        The kernel builds the basis once per (row block, feature block) and
+        runs this many MACs on it; at 1 it keeps no basis scratch.
+        """
+        return self.op // self.bo
+
 
 @dataclasses.dataclass(frozen=True)
 class PipelinePlan:
@@ -128,7 +140,8 @@ def _tile_bytes(rows: int, cols: int, itemsize: int = 4) -> int:
             * itemsize)
 
 
-def vmem_bytes(bb: int, bf: int, bo: int, spec: ASPQuantSpec) -> int:
+def vmem_bytes(bb: int, bf: int, bo: int, op: int,
+               spec: ASPQuantSpec) -> int:
     """Estimated VMEM working set of one fused-layer grid step.
 
     Counts every block with lane/sublane padding: the double-buffered
@@ -136,6 +149,10 @@ def vmem_bytes(bb: int, bf: int, bo: int, spec: ASPQuantSpec) -> int:
     noise, y, out codes — the worst case over backends and packing), the
     unpacked int4 weight scratch, the 0/1 expansion matrix, the
     (bb, bf*(G+K)) band/basis planes and the K+1 per-feature LUT planes.
+    When the layer has more than one output tile (``op > bo``) it adds the
+    scratch that outlives a grid step: the (bb, bf*(G+K)) basis and the
+    (bb, bf) ReLU operand, built once per (row block, feature block), and
+    the f32 accumulator of the whole (bb, op) output row block.
     """
     nbf = bf * spec.num_basis
     io = (2 * _tile_bytes(bb, bf) + _tile_bytes(nbf, bo) + _tile_bytes(bf, bo)
@@ -143,6 +160,9 @@ def vmem_bytes(bb: int, bf: int, bo: int, spec: ASPQuantSpec) -> int:
     temps = (_tile_bytes(nbf, bo) + _tile_bytes(bf, nbf)
              + 3 * _tile_bytes(bb, nbf)
              + (spec.order + 4) * _tile_bytes(bb, bf))
+    if op > bo:
+        temps += (_tile_bytes(bb, nbf) + _tile_bytes(bb, bf)
+                  + (op // bo) * _tile_bytes(bb, bo))
     return 2 * io + temps
 
 
@@ -193,6 +213,11 @@ def make_pipeline_plan(
     the 128-padded inter-layer boundary contract is untouched.  Invalid
     overrides (non-power-of-two, not dividing the padded dim, a working
     set over the VMEM budget) raise ``ValueError``.
+
+    The kernel's grid is ``(Bp/bb, Fp/bf, Op/bo)`` with the output tile
+    innermost, so a layer holds the f32 accumulator of a whole
+    ``(bb, op)`` row block in VMEM: :func:`vmem_bytes` counts it, and the
+    heuristic halves ``bb`` until every layer fits.
     """
     n_layers = len(dims) - 1
     if len(specs) != n_layers:
@@ -242,7 +267,7 @@ def make_pipeline_plan(
         # the batch block is the one free dim: halve it until every layer's
         # working set fits
         while bb > 8 and any(
-            vmem_bytes(bb, lp.bf, lp.bo, lp.spec) > _VMEM_BUDGET
+            vmem_bytes(bb, lp.bf, lp.bo, lp.op, lp.spec) > _VMEM_BUDGET
             for lp in layers
         ):
             bb = _round_up(bb // 2, 8)
@@ -254,11 +279,11 @@ def make_pipeline_plan(
 
 
 def _check_vmem(li: int, lp: LayerPlan) -> None:
-    need = vmem_bytes(lp.bb, lp.bf, lp.bo, lp.spec)
+    need = vmem_bytes(lp.bb, lp.bf, lp.bo, lp.op, lp.spec)
     if need > _VMEM_BUDGET:
         raise ValueError(
             f"layer {li}: tile {lp.bb}x{lp.bf}x{lp.spec.num_basis} -> "
-            f"{lp.bo} needs ~{need >> 20} MiB of VMEM, over the "
+            f"{lp.bo} of {lp.op} needs ~{need >> 20} MiB of VMEM, over the "
             f"{_VMEM_BUDGET >> 20} MiB budget"
         )
 
@@ -547,13 +572,24 @@ def _pipeline_layer_kernel(
     """One KAN layer tile + (optionally) the fused inter-layer requantizer.
 
     Ref order: codes, [xraw], lut (SMEM), wc | (wcp, wscale), wb,
-    [psum_noise], y_out, [codes_out], [wq scratch (packed_w only)].
-    Grid: (Bp/bb, Op/bo, Fp/bf); the F axis (last) is the contraction —
-    y_out accumulates across it, the boundary fires on the final step.
+    [psum_noise], y_out, [codes_out], [wq scratch (packed_w only)],
+    [basis, relu operand, accumulator scratch (basis_reuse > 1 only)].
+    Grid: (Bp/bb, Fp/bf, Op/bo); the F axis (middle) is the contraction,
+    the output tile j is innermost.  A (bb, bo) output tile accumulates
+    across the F axis in ascending order, and the boundary fires on the
+    final contraction step of each output tile.
 
     The datapath up to the MAC is :func:`banded_basis`; the spline MAC is
     then ONE (bb, bf*(G+K)) x (bf*(G+K), bo) dot over the deployed row
-    order, the same contraction the reference runs.
+    order, the same contraction the reference runs.  The basis depends on
+    the (i, k) code tile alone: with ``lp.basis_reuse > 1`` it is built on
+    the first output tile (j == 0) into VMEM scratch, with the ReLU
+    operand, and every j reads it back; the (bb, op) accumulator lives in
+    scratch, and the (i, j) output tile is written on the last k.  With
+    one output tile the basis is built inline and the output block is the
+    accumulator, with no scratch round-trip.  Either way each (i, j, k)
+    MAC is the same dot on the same operands, so the bits do not depend on
+    the schedule.
 
     ``packed_w`` (static, from the deployed dict's keys): the weight operand
     arrives as two int4 codes per int8 lane and is unpacked HERE — int32
@@ -587,14 +623,36 @@ def _pipeline_layer_kernel(
     codes_out_ref = None
     if lp.emit_codes:
         codes_out_ref = refs[idx]; idx += 1
-    wq_ref = refs[idx] if packed_w else None
+    wq_ref = None
+    if packed_w:
+        wq_ref = refs[idx]; idx += 1
+    reuse = lp.basis_reuse > 1
+    if reuse:
+        basis_ref, relu_ref, acc_ref = refs[idx:idx + 3]
 
     spec = lp.spec
-    k_step = pl.program_id(2)
-    n_k = pl.num_programs(2)
+    k_step = pl.program_id(1)
+    n_k = pl.num_programs(1)
+    j = pl.program_id(2)
 
-    codes = codes_ref[...]
-    basis = banded_basis(codes, lut_ref, spec)
+    def relu_operand():
+        if lp.residual_raw:
+            resid = xraw_ref[...].astype(jnp.float32)
+        else:
+            codes = codes_ref[...].astype(jnp.float32)
+            resid = spec.lo + codes * spec.code_step
+        return jnp.maximum(resid, 0.0)
+
+    if reuse:
+        @pl.when(j == 0)
+        def _build_basis():
+            basis_ref[...] = banded_basis(codes_ref[...], lut_ref, spec)
+            relu_ref[...] = relu_operand()
+
+        basis, relu = basis_ref[...], relu_ref[...]
+    else:
+        basis = banded_basis(codes_ref[...], lut_ref, spec)
+        relu = relu_operand()
 
     # --- spline MAC on the MXU
     if packed_w:
@@ -612,22 +670,26 @@ def _pipeline_layer_kernel(
     acc = _dot(basis, wc_tile)
 
     # --- fused residual branch
-    if lp.residual_raw:
-        resid = xraw_ref[...].astype(jnp.float32)
-    else:
-        resid = spec.lo + codes.astype(jnp.float32) * spec.code_step
-    acc = acc + _dot(jnp.maximum(resid, 0.0), wb_ref[...].astype(jnp.float32))
+    acc = acc + _dot(relu, wb_ref[...].astype(jnp.float32))
+
+    # the (bb, bo) accumulator of output tile j
+    acc_j = acc_ref.at[j] if reuse else y_ref
 
     @pl.when(k_step == 0)
     def _init():
         if noise_ref is not None:
-            y_ref[...] = acc + noise_ref[...]
+            acc_j[...] = acc + noise_ref[...]
         else:
-            y_ref[...] = acc
+            acc_j[...] = acc
 
     @pl.when(k_step > 0)
     def _accum():
-        y_ref[...] += acc
+        acc_j[...] += acc
+
+    if reuse:
+        @pl.when(k_step == n_k - 1)
+        def _emit():
+            y_ref[...] = acc_j[...]
 
     if lp.emit_codes:
         nxt = lp.next_spec
@@ -669,12 +731,13 @@ def _run_layer(
         assert lw["wc"].shape == (lp.fp * nb, lp.op), (
             lw["wc"].shape, lp.fp, nb, lp.op)
 
-    grid = (bp // lp.bb, lp.op // lp.bo, lp.fp // lp.bf)
+    n_k = lp.fp // lp.bf
+    grid = (bp // lp.bb, n_k, lp.basis_reuse)
 
-    in_specs = [pl.BlockSpec((lp.bb, lp.bf), lambda i, j, k: (i, k))]
+    in_specs = [pl.BlockSpec((lp.bb, lp.bf), lambda i, k, j: (i, k))]
     inputs = [codes]
     if lp.residual_raw:
-        in_specs.append(pl.BlockSpec((lp.bb, lp.bf), lambda i, j, k: (i, k)))
+        in_specs.append(pl.BlockSpec((lp.bb, lp.bf), lambda i, k, j: (i, k)))
         inputs.append(xraw)
     # the (2**LD, K+1) SH-LUT is read as scalars
     in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
@@ -684,25 +747,36 @@ def _run_layer(
         # bf >= 8 keeps bf*nb even, so every contraction block owns whole
         # packed lanes and the (k, j) index map stays contiguous
         in_specs += [
-            pl.BlockSpec((lp.bf * nb // 2, lp.bo), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1, lp.bo), lambda i, j, k: (0, j)),
+            pl.BlockSpec((lp.bf * nb // 2, lp.bo), lambda i, k, j: (k, j)),
+            pl.BlockSpec((1, lp.bo), lambda i, k, j: (0, j)),
         ]
         inputs += [lw["wcp"], lw["wscale"]]
         scratch.append(pltpu.VMEM((lp.bf * nb, lp.bo), jnp.float32))
     else:
-        in_specs.append(pl.BlockSpec((lp.bf * nb, lp.bo), lambda i, j, k: (k, j)))
+        in_specs.append(pl.BlockSpec((lp.bf * nb, lp.bo), lambda i, k, j: (k, j)))
         inputs.append(lw["wc"])
-    in_specs.append(pl.BlockSpec((lp.bf, lp.bo), lambda i, j, k: (k, j)))
+    in_specs.append(pl.BlockSpec((lp.bf, lp.bo), lambda i, k, j: (k, j)))
     inputs.append(lw["wb"])
     if psum_noise is not None:
         assert psum_noise.shape == (bp, lp.op), (psum_noise.shape, bp, lp.op)
-        in_specs.append(pl.BlockSpec((lp.bb, lp.bo), lambda i, j, k: (i, j)))
+        in_specs.append(pl.BlockSpec((lp.bb, lp.bo), lambda i, k, j: (i, j)))
         inputs.append(psum_noise)
+    if lp.basis_reuse > 1:
+        scratch += [
+            pltpu.VMEM((lp.bb, lp.bf * nb), jnp.float32),   # basis
+            pltpu.VMEM((lp.bb, lp.bf), jnp.float32),        # relu operand
+            pltpu.VMEM((lp.basis_reuse, lp.bb, lp.bo), jnp.float32),  # y acc
+        ]
 
-    out_specs = [pl.BlockSpec((lp.bb, lp.bo), lambda i, j, k: (i, j))]
+    # An output tile is written on the last contraction step alone; until
+    # then its block index stays put, so no partial tile is written back.
+    def out_index(i, k, j):
+        return i, jnp.where(k == n_k - 1, j, 0)
+
+    out_specs = [pl.BlockSpec((lp.bb, lp.bo), out_index)]
     out_shape = [jax.ShapeDtypeStruct((bp, lp.op), jnp.float32)]
     if lp.emit_codes:
-        out_specs.append(pl.BlockSpec((lp.bb, lp.bo), lambda i, j, k: (i, j)))
+        out_specs.append(pl.BlockSpec((lp.bb, lp.bo), out_index))
         out_shape.append(jax.ShapeDtypeStruct((bp, lp.op), jnp.int32))
 
     kernel = functools.partial(
@@ -717,7 +791,7 @@ def _run_layer(
         out_shape=out_shape,
         scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT,
         ),
         interpret=interpret,

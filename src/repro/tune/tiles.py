@@ -156,15 +156,17 @@ def plan_cost_proxy(plan: PipelinePlan) -> float:
     Models the two things tiling actually changes at fixed padded dims:
     per-tile dispatch overhead (finer grids pay more fixed cost) and
     padded-batch waste (``bp`` grows with ``bb``).  Compute volume itself is
-    tile-invariant, so it enters only through ``bp``.
+    tile-invariant, so it enters only through ``bp``: the kernel builds
+    each basis tile once per (row block, feature block), whatever ``bo``.
     """
     C0 = 4096.0  # fixed per-tile dispatch/prologue cost, flop-equivalents
     total = 0.0
     for lp in plan.layers:
         nb = lp.spec.num_basis
-        cells = (plan.bp // lp.bb) * (lp.op // lp.bo) * (lp.fp // lp.bf)
-        tile_work = lp.bb * lp.bf * nb * (1.0 + lp.bo)  # basis build + MAC
-        total += cells * (C0 + tile_work)
+        basis_tiles = (plan.bp // lp.bb) * (lp.fp // lp.bf)
+        cells = basis_tiles * lp.basis_reuse
+        tile_work = lp.bb * lp.bf * nb
+        total += basis_tiles * tile_work + cells * (C0 + tile_work * lp.bo)
     return total
 
 

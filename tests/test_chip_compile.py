@@ -90,6 +90,29 @@ def test_kan_pipeline_compiles_for_v5e(one_chip, case):
     _assert_mosaic(jax.jit(run).lower(codes, xraw, layers).compile())
 
 
+@pytest.mark.parametrize("li", [0, 1])
+def test_kan_mesh_local_layer_compiles_for_v5e(one_chip, li):
+    """One model shard of a data=2,model=2 mesh: the prefill KAN-FFN plan
+    split over model=2 gives local widths 640 and 2560 (5 and 20 output
+    tiles per basis tile), which the mesh runner drives one layer at a
+    time."""
+    specs = (_spec(8, 8), _spec(8, 8))
+    plan = P.make_pipeline_plan(256, (5120, 1280, 5120), specs,
+                                residual_raw=True)
+    local, sharded, _ = P.shard_local_plan(plan, 2)
+    assert sharded == (True, True)
+    lp = local.layers[li]
+    assert (lp.op, lp.basis_reuse) == ((640, 5), (2560, 20))[li]
+    lw = _layer_shapes(lp, one_chip)
+    codes = _sds((local.bp, lp.fp), jnp.int32, one_chip)
+    xraw = _sds((local.bp, lp.fp), jnp.float32, one_chip)
+
+    def run(c, x, w):
+        return P.run_pipeline_layer(c, x, w, lp, local.bp, interpret=False)
+
+    _assert_mosaic(jax.jit(run).lower(codes, xraw, lw).compile())
+
+
 # qwen2.5-14b attention: 48 padded q heads over 8 KV heads (G=6) x 128,
 # a batch of 8 decoding one token over a 2048-token cache, and a batch of 2
 # prefilling 512 tokens.
