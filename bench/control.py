@@ -32,9 +32,10 @@ import run
 from harness import common, faults, kan, lm
 
 
-def lm_reading(conf, traffic, seed, seconds, fault=None):
-    """(cfg, window, the widest gap, detail) of one served window."""
-    engine, cfg, _ = lm.setup(conf, traffic, seed)
+def lm_reading(conf, traffic, seed, seconds, chips, fault=None):
+    """(cfg, mesh, window, the widest gap, detail) of one served window."""
+    engine, cfg, _ = lm.setup(conf, traffic, seed, chips)
+    mesh = engine.mesh
     if fault:
         faults.LM[fault](engine)
     win = lm.serve(engine, cfg, traffic, seed, seconds)
@@ -43,21 +44,24 @@ def lm_reading(conf, traffic, seed, seconds, fault=None):
     del engine
     gc.collect()
     checks, detail = lm.check_served(conf, win, seed, traffic["max_len"],
-                                     math.inf)
+                                     math.inf, mesh)
     name, value = checks.items[0][:2]
-    return cfg, win, value if name == lm.GAP_LIMIT_KEY else None, detail
+    return (cfg, mesh, win, value if name == lm.GAP_LIMIT_KEY else None,
+            detail)
 
 
-def lm_seed(conf, traffic, seed, seconds, control, fault_names):
-    cfg, win, gap, detail = lm_reading(conf, traffic, seed, seconds)
+def lm_seed(conf, traffic, seed, seconds, chips, control, fault_names):
+    cfg, mesh, win, gap, detail = lm_reading(conf, traffic, seed, seconds,
+                                             chips)
     row = {"seed": seed, "program": gap, **detail}
     if control and gap is not None:
-        row["control"], extra = lm.control_gap(conf, cfg, traffic, win, seed)
+        row["control"], extra = lm.control_gap(conf, cfg, traffic, win, seed,
+                                               mesh=mesh)
         row.update(extra)
         for name in fault_names:
             gc.collect()
             row[f"fault.{name}"] = lm_reading(conf, traffic, seed, seconds,
-                                              name)[2]
+                                              chips, name)[3]
     return row
 
 
@@ -76,7 +80,7 @@ def kan_reading(conf, traffic, seed, seconds, fault=None):
     return win, xs, value if name == "max_row_err" else None, detail
 
 
-def kan_seed(conf, traffic, seed, seconds, control, fault_names):
+def kan_seed(conf, traffic, seed, seconds, chips, control, fault_names):
     import jax.numpy as jnp
 
     from reference.kan import make_weights, outputs, row_errors
@@ -107,14 +111,14 @@ def main() -> int:
     ap.add_argument("--seconds", type=float, default=10.0)
     args = ap.parse_args()
     _, cell, conf, traffic = common.load_cell(args.workload, run.REPO)
-    run.accelerators(int(cell["chips"]))
+    chips = len(run.accelerators(int(cell["chips"])))
     run.use_cache()
     ctrl = {int(s) for s in args.control_seeds.split(",") if s}
     fault_names = [f for f in args.faults.split(",") if f]
     one = {"lm": lm_seed, "kan": kan_seed}[conf["kind"]]
     for s in (int(x) for x in args.seeds.split(",")):
-        print(json.dumps(one(conf, traffic, s, args.seconds, s in ctrl,
-                             fault_names)), flush=True)
+        print(json.dumps(one(conf, traffic, s, args.seconds, chips,
+                             s in ctrl, fault_names)), flush=True)
         gc.collect()
     return 0
 

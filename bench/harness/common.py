@@ -2,9 +2,10 @@
 
 Everything a cell needs is found by the names in ``BENCHMARK.json``:
 ``configs/<config>.json``, ``traffic/<traffic>.json`` and
-``metrics/<metric>.py`` under the benchmark directory, so a new cell,
-traffic mix or per-layer metric is a new file and a new entry, never an
-edit of an existing file.
+``metrics/<metric>.py`` under the benchmark directory, and the runner of
+the configuration's ``kind`` as ``harness/<kind>.py``, so a new cell,
+traffic mix, per-layer metric or kind of configuration is a new file and
+a new entry, never an edit of an existing file.
 """
 
 from __future__ import annotations
@@ -13,6 +14,7 @@ import importlib.util
 import json
 import math
 import pathlib
+import re
 import sys
 
 BENCH = pathlib.Path(__file__).resolve().parents[1]
@@ -70,9 +72,12 @@ def load_cell(workload: str, root: pathlib.Path = REPO) -> tuple:
 
 
 def load_module(path: pathlib.Path, name: str | None = None):
+    """The module in ``path``, registered under ``name`` before it runs (a
+    dataclass looks its module up while it is being defined)."""
     spec = importlib.util.spec_from_file_location(
         name or f"bench_{path.stem.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod
     spec.loader.exec_module(mod)
     return mod
 
@@ -80,6 +85,25 @@ def load_module(path: pathlib.Path, name: str | None = None):
 def metric_reader(name: str, base: pathlib.Path = BENCH):
     """The ``read(ctx)`` function of ``metrics/<name>.py``."""
     return load_module(base / "metrics" / f"{name}.py").read
+
+
+def runner(kind: str, base: pathlib.Path = BENCH):
+    """``harness/<kind>.py``, whose ``run(conf, traffic, args, devices,
+    counter, profiler)`` runs a cell of that kind of configuration.  It
+    is loaded as a module of this package (its relative imports find the
+    harness), once: a module already loaded from the same file is reused."""
+    path = base / "harness" / f"{kind}.py"
+    if not re.fullmatch(r"[A-Za-z_][A-Za-z0-9_]*", kind) or not path.is_file():
+        raise SystemExit(f"no runner for configuration kind {kind!r}: "
+                         f"{path} is not a file")
+    name = f"{__package__}.{kind}"
+    mod = sys.modules.get(name)
+    if mod is None or pathlib.Path(mod.__file__).resolve() != path.resolve():
+        mod = load_module(path, name)
+    if not callable(getattr(mod, "run", None)):
+        raise SystemExit(f"{path} has no run() for configuration kind "
+                         f"{kind!r}")
+    return mod
 
 
 # --- seeds -------------------------------------------------------------------

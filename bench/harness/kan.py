@@ -14,10 +14,45 @@ from __future__ import annotations
 import collections
 import time
 
+from . import common
 from .common import Checks, jax_key, np_rng
 
 KEEP = 6          # sampled calls whose outputs are checked
 KERNEL = "kan_pipeline"
+
+
+def run(conf, traffic, args, devices, counter, profiler) -> dict:
+    """One run of a KAN network cell (see ``run.py``): set-up, the window,
+    then the reference over the sampled calls."""
+    info = {}
+    t = time.perf_counter()
+    dep, xs = setup(conf, traffic, args.seed)
+    info["init_quantize_s"] = time.perf_counter() - t
+    t = time.perf_counter()
+    warm_up(dep, xs)
+    info["warmup_s"] = time.perf_counter() - t
+    setup_end = time.perf_counter()
+    counter.armed = True
+    win = serve(dep, xs, traffic, args.seed, args.seconds, profiler=profiler)
+    counter.armed = False
+    mem = common.peak_bytes(devices)
+    e2e = metrics(win, traffic)
+    info.update(e2e)
+    ctx = None
+    if args.trace:
+        from roofline import peaks
+
+        ctx = {"kind": "kan", "conf": conf, "traffic": traffic,
+               "trace": profiler.reduce(kernels=(KERNEL,)),
+               "peaks": peaks(devices[0].device_kind)}
+    del dep
+    checks, detail = check(conf, traffic, win, xs, args.seed,
+                           traffic.get("correct", conf["correct"])
+                           ["max_row_err"])
+    info.update(detail)
+    return {"e2e": e2e, "ctx": ctx, "checks": checks,
+            "attempted": win.done_in_window, "failed": 0,
+            "memory_peak_bytes": mem, "info": info, "setup_end": setup_end}
 
 
 def program_spec(conf: dict):

@@ -1,6 +1,7 @@
 """LM cells: the program's serving path under open- or closed-loop load.
 
-Set-up makes the weights on the device from the seed, builds
+Set-up makes the weights on the cell's chips from the seed (on a mesh,
+each leaf where the program's sharding rules serve it), builds
 ``ServeEngine(kan_deploy=True, attn_backend="flash")`` on the paged KV pool
 with the cell's slots, and warms up exactly the programs the traffic will
 use (every prefill-chunk bucket and the decode step) through a Scheduler.
@@ -13,11 +14,13 @@ the plain reference scores a seeded sample of the finished requests.
 from __future__ import annotations
 
 import dataclasses
+import gc
 import time
 
 import numpy as np
 
 from . import traffic as T
+from . import common
 from .common import Checks, jax_key, nearest_rank, np_rng
 
 # The widest gap, in logits, by which a served token may lie below the
@@ -30,6 +33,67 @@ DECODE_PROGRAM, PREFILL_PROGRAM = "_decode_paged", "_prefill_chunk_fn"
 SAMPLE_TOKENS = 512       # served tokens the reference scores, at least,
 SAMPLE_MIN_REQUESTS = 4   # over at least this many requests,
 SAMPLE_MAX_REQUESTS = 12  # and at most this many
+
+
+# --- one run ---------------------------------------------------------------------
+
+
+def run(conf, traffic, args, devices, counter, profiler) -> dict:
+    """One run of an LM cell (see ``run.py``): set-up, the window, then the
+    reference over a sample of what it served."""
+    engine, cfg, info = setup(conf, traffic, args.seed, len(devices))
+    setup_end = time.perf_counter()
+    mesh = engine.mesh
+
+    counter.armed = True
+    win = serve(engine, cfg, traffic, args.seed, args.seconds,
+                trace=bool(args.trace), profiler=profiler)
+    counter.armed = False
+    info["programs_after_window"] = counts(engine)
+    mem = common.peak_bytes(devices)
+    e2e = window_metrics(win, traffic)
+    for k in ("window_s", "due_in_window", "finished", "output_tokens",
+              "prompt_tokens", "ttft_p90_ms", "itl_p95_ms", "tokens_per_s"):
+        info[k] = e2e[k]
+    info["ttft_samples_beyond_p90"] = common.beyond(len(e2e["ttft_s"]), 0.9)
+    ctx = None
+    if args.trace:
+        ctx = context(conf, traffic, win, profiler, devices)
+    # free the program's state before the reference runs (its weights and
+    # KV pool go even if a reference cycle keeps the engine object alive)
+    sched_requests = win.requests
+    win.sched = None
+    engine.params = engine.cache = None
+    del engine
+    gc.collect()
+    limit = traffic.get("correct", conf["correct"])[GAP_LIMIT_KEY]
+    checks, detail = check_served(conf, win, args.seed, traffic["max_len"],
+                                  limit, mesh)
+    info.update(detail)
+    # open loop: the requests due in the window; closed loop: those that
+    # emitted a token inside it
+    attempted = e2e["due_in_window"] if traffic["kind"] == "open_loop" else \
+        sum(1 for ts in win.times.values()
+            if any(win.w0 <= t < win.w1 for t in ts))
+    failed = sum(1 for r in sched_requests.values()
+                 if r.status in ("expired", "rejected"))
+    return {"e2e": e2e, "ctx": ctx, "checks": checks, "attempted": attempted,
+            "failed": failed, "memory_peak_bytes": mem, "info": info,
+            "setup_end": setup_end}
+
+
+def context(conf, traffic, win, profiler, devices) -> dict:
+    """What the per-layer metric readers of an LM cell read."""
+    from roofline import peaks
+
+    red = profiler.reduce(kernels=(KAN_KERNEL, ATTN_KERNEL),
+                          programs=(DECODE_PROGRAM, PREFILL_PROGRAM))
+    t0, t1 = win.trace_span
+    return {"kind": "lm", "conf": conf, "traffic": traffic, "trace": red,
+            "peaks": peaks(devices[0].device_kind),
+            "steps": [s for s in win.steps if t0 <= s[1] < t1],
+            "spans": win.sched_records, "due": win.due,
+            "window": win.due_window(), "end": win.w1}
 
 
 # --- configuration -------------------------------------------------------------
@@ -62,18 +126,37 @@ def program_config(conf: dict):
     return cfg
 
 
-def make_program_params(conf: dict, cfg, seed: int):
-    """The program's parameter tree, made on the device in one call."""
+def cell_mesh(traffic: dict, chips: int):
+    """The mesh a cell serves on: none on one chip; on several,
+    ``engine.mesh`` of the traffic file, else every chip on the model axis."""
+    if chips == 1:
+        return None
+    from repro.launch.mesh import parse_mesh_spec
+
+    return parse_mesh_spec(traffic.get("engine", {}).get("mesh",
+                                                         f"model={chips}"))
+
+
+def make_program_params(conf: dict, cfg, seed: int, mesh=None):
+    """The program's parameter tree, made on the device in one call.  On a
+    mesh each leaf is made where the program's own sharding rules
+    (``param_pspecs``) put it, so no chip holds the whole tree; the values
+    do not depend on the mesh."""
     import jax
 
     from repro.models.model import init_params
 
     from .lm_weights import canonical, program
 
-    fn = jax.jit(lambda key: program(conf, canonical(conf, key),
-                                     cfg.phys_heads))
-    params = fn(jax_key(seed, 1))
     want = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    shardings = None
+    if mesh is not None:
+        from repro.dist.sharding import param_pspecs, to_shardings
+
+        shardings = to_shardings(param_pspecs(want, mesh), mesh)
+    fn = jax.jit(lambda key: program(conf, canonical(conf, key),
+                                     cfg.phys_heads), out_shardings=shardings)
+    params = fn(jax_key(seed, 1))
     got_s = jax.tree.map(lambda a: (a.shape, a.dtype), params)
     want_s = jax.tree.map(lambda a: (a.shape, a.dtype), want)
     if got_s != want_s:
@@ -83,35 +166,33 @@ def make_program_params(conf: dict, cfg, seed: int):
     return params
 
 
-def build_engine(params, cfg, traffic: dict, chips: int = 1):
+def build_engine(params, cfg, traffic: dict, mesh=None):
     """The program's ``ServeEngine`` with the mix's slots and pool, and the
-    options that the traffic file's ``engine`` entry names.  A cell on
-    several chips serves on a mesh over them: ``engine.mesh`` in the file,
-    else every chip on the model axis."""
-    from repro.launch.mesh import parse_mesh_spec
+    options that the traffic file's ``engine`` entry names, serving on
+    ``mesh`` (see ``cell_mesh``)."""
     from repro.serve.engine import ServeEngine
 
     opts = dict(traffic.get("engine", {}))
-    mesh = opts.pop("mesh", f"model={chips}")
+    opts.pop("mesh", None)
     return ServeEngine(params, cfg, slots=traffic["slots"],
                        max_len=traffic["max_len"],
                        kv_block_size=traffic["kv_block_size"],
-                       prefill_chunk=traffic["prefill_chunk"],
-                       mesh=parse_mesh_spec(mesh) if chips > 1 else None,
+                       prefill_chunk=traffic["prefill_chunk"], mesh=mesh,
                        **opts)
 
 
 def setup(conf: dict, traffic: dict, seed: int, chips: int = 1) -> tuple:
-    """(engine, cfg, timings): the weights made on the device from the
+    """(engine, cfg, timings): the weights made on the cell's chips from the
     seed, the engine built on them (the KAN-FFN quantized), and every
     program the cell's traffic uses warmed up."""
     info = {}
     cfg = program_config(conf)
+    mesh = cell_mesh(traffic, chips)
     t = time.perf_counter()
-    params = make_program_params(conf, cfg, seed)
+    params = make_program_params(conf, cfg, seed, mesh)
     info["init_s"] = time.perf_counter() - t
     t = time.perf_counter()
-    engine = build_engine(params, cfg, traffic, chips)
+    engine = build_engine(params, cfg, traffic, mesh)
     del params
     info["quantize_s"] = time.perf_counter() - t
     t = time.perf_counter()
@@ -389,15 +470,22 @@ def sample_finished(win: Window, seed: int) -> list:
     return out
 
 
-def reference_for(conf: dict, seed: int, max_len: int, bits=None):
-    """The plain reference over weights made again from the seed."""
+def reference_for(conf: dict, seed: int, max_len: int, bits=None,
+                  mesh=None):
+    """The plain reference over weights made again from the seed; on a
+    mesh the weights are made spread over its chips (``reference.lm.spread``),
+    with the same values."""
     import jax
 
-    from reference.lm import Reference
+    from reference.lm import Reference, spread
 
     from .lm_weights import canonical
 
-    w = jax.jit(lambda k: canonical(conf, k))(jax_key(seed, 1))
+    make = lambda k: canonical(conf, k)
+    shardings = None
+    if mesh is not None:
+        shardings = spread(jax.eval_shape(make, jax_key(seed, 1)), mesh)
+    w = jax.jit(make, out_shardings=shardings)(jax_key(seed, 1))
     return Reference(conf, w, max_len, bits=bits)
 
 
@@ -418,7 +506,7 @@ def logit_gaps(ref, req_tokens: list) -> list:
 
 
 def check_served(conf: dict, win: Window, seed: int, max_len: int,
-                 limit: float) -> tuple:
+                 limit: float, mesh=None) -> tuple:
     """Score the sample against the reference; returns (Checks, detail)."""
     sample = sample_finished(win, seed)
     checks = Checks()
@@ -426,7 +514,7 @@ def check_served(conf: dict, win: Window, seed: int, max_len: int,
         checks.add("finished_requests", 0, 1, ok=False)
         return checks, {}
     t0 = time.perf_counter()
-    ref = reference_for(conf, seed, max_len)
+    ref = reference_for(conf, seed, max_len, mesh=mesh)
     gaps = logit_gaps(ref, [(r.prompt, r.output) for r in sample])
     worst = float(max(g.max() for g in gaps))
     n_tok = int(sum(g.size for g in gaps))
@@ -438,21 +526,22 @@ def check_served(conf: dict, win: Window, seed: int, max_len: int,
 
 
 def control_gap(conf: dict, cfg, traffic: dict, win: Window, seed: int,
-                bits: int = 4) -> tuple:
+                bits: int = 4, mesh=None) -> tuple:
     """The control: the program's own lower-precision KAN-FFN path
     (``kan_n_bits=bits``), teacher-forced over the sampled requests'
     prompts and served tokens; returns (the widest gap of the tokens it
     puts first, detail)."""
     sample = sample_finished(win, seed)
     low = dataclasses.replace(cfg, kan_n_bits=bits)
-    engine = build_engine(make_program_params(conf, cfg, seed), low, traffic)
+    engine = build_engine(make_program_params(conf, cfg, seed, mesh), low,
+                          traffic, mesh)
     picks = []
     for r in sample:
         rows = engine.teacher_forced_logits(r.prompt, r.output[:-1])
         picks.append((r.prompt, r.output, np.argmax(rows, axis=-1).tolist()))
     engine.params = engine.cache = None
     del engine
-    ref = reference_for(conf, seed, traffic["max_len"])
+    ref = reference_for(conf, seed, traffic["max_len"], mesh=mesh)
     gaps = logit_gaps(ref, [(p, c) for p, _, c in picks])
     agree = float(np.mean(np.concatenate(
         [np.equal(o, c) for _, o, c in picks])))

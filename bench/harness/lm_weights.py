@@ -82,17 +82,16 @@ def canonical(conf: dict, key) -> dict:
 
 def _pad_heads(w, axis: int, hkv: int, phys: int):
     """Spread ``hq`` logical heads (axis ``axis``) over ``phys`` slots in
-    ``hkv`` groups, zero slots at the end of each group."""
+    ``hkv`` groups, zero slots at the end of each group.  A reshape and a
+    pad, not a concatenation: on a mesh that shards the heads the
+    partitioner then keeps each chip's groups on that chip."""
     hq = w.shape[axis]
     g, gp = hq // hkv, phys // hkv
-    parts = jnp.split(w, hkv, axis=axis)
-    pad_shape = list(w.shape)
-    pad_shape[axis] = gp - g
-    zeros = jnp.zeros(pad_shape, w.dtype)
-    out = []
-    for p in parts:
-        out += [p, zeros]
-    return jnp.concatenate(out, axis=axis)
+    grouped = w.reshape(w.shape[:axis] + (hkv, g) + w.shape[axis + 1:])
+    widths = [(0, 0)] * grouped.ndim
+    widths[axis + 1] = (0, gp - g)
+    padded = jnp.pad(grouped, widths)
+    return padded.reshape(w.shape[:axis] + (phys,) + w.shape[axis + 1:])
 
 
 def program(conf: dict, w: dict, phys_heads: int) -> dict:

@@ -4,7 +4,10 @@ Sizes and gaps are drawn by stratified inverse-CDF sampling in blocks:
 each block of ``block`` requests holds the same set of prompt lengths,
 output lengths and arrival gaps for every seed, in a seed-drawn order.
 So two seeds offer the same work, and a window covering whole blocks sees
-the same mix; only the order and the token ids change.
+the same mix; only the order and the token ids change.  A mix that names
+``order_seed`` draws that order from it instead of from the run's seed:
+every seed then serves the same schedule, and only token ids (and weights)
+change.
 
 Parameters (all in the traffic file):
 
@@ -21,6 +24,8 @@ Parameters (all in the traffic file):
 ``shared_prefix``  optional {"tokens", "groups"}: requests of one group
                    (rid modulo groups) share their first ``tokens`` tokens.
 ``block``          requests per stratified block.
+``order_seed``     optional: the number the blocks' order is drawn from,
+                   in place of the run's seed.
 """
 
 from __future__ import annotations
@@ -85,9 +90,10 @@ def plan(traffic: dict, seed: int, count: int) -> list:
     at once."""
     block = int(traffic.get("block", 32))
     rate = traffic.get("rate_per_s")
+    order = traffic.get("order_seed", seed)
     out, unit, t = [], [], 0.0
     for b in range(math.ceil(count / block)):
-        rng = np_rng(seed, _SIZES_TAG, b)
+        rng = np_rng(order, _SIZES_TAG, b)
         plens = draw_lengths(traffic["prompt_tokens"], block, rng)
         olens = draw_lengths(traffic["output_tokens"], block, rng)
         gaps = -np.log1p(-_quantiles(block))

@@ -4,7 +4,8 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell, its configuration and its traffic mix are found by name from
-``BENCHMARK.json`` (see ``harness/common.py``).  The run refuses to start
+``BENCHMARK.json``, and the runner of the configuration's ``kind`` as
+``harness/<kind>.py`` (see ``harness/common.py``).  The run refuses to start
 unless JAX's first device is an accelerator and there are as many as the
 cell asks for; it makes weights and inputs from ``--seed``, warms up the
 cell's programs (``setup_s``), measures ``--seconds`` of load, checks what
@@ -25,7 +26,6 @@ import os  # noqa: E402
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 import argparse  # noqa: E402
-import gc  # noqa: E402
 import pathlib  # noqa: E402
 import sys  # noqa: E402
 
@@ -102,6 +102,7 @@ def main(argv=None, root: pathlib.Path = REPO) -> int:
     args = parse(argv)
     base = root / BENCH.name
     bench, cell, conf, traffic = common.load_cell(args.workload, root)
+    runner = common.runner(conf["kind"], base)
     devices = accelerators(int(cell["chips"]))
 
     log(f"compile cache: {use_cache()}")
@@ -115,8 +116,9 @@ def main(argv=None, root: pathlib.Path = REPO) -> int:
         profiler = Profiler(str(root / ".bench_trace" / args.workload),
                             min(TRACE_SECONDS, args.seconds))
 
-    run_cell = {"lm": run_lm, "kan": run_kan}[conf["kind"]]
-    out = run_cell(conf, traffic, args, devices, counter, profiler)
+    out = runner.run(conf, traffic, args, devices, counter, profiler)
+    setup_s = out["setup_end"] - T0
+    out["e2e"]["setup_s"] = out["info"]["setup_s"] = setup_s
 
     metrics = {}
     for m in wanted(bench["per_layer"] if args.trace else bench["end_to_end"],
@@ -148,102 +150,6 @@ def main(argv=None, root: pathlib.Path = REPO) -> int:
         metrics=metrics, device=device, checks=checks, breakdown=breakdown),
         flush=True)
     return 0
-
-
-def run_lm(conf, traffic, args, devices, counter, profiler) -> dict:
-    from harness import lm
-
-    engine, cfg, info = lm.setup(conf, traffic, args.seed, len(devices))
-    setup_s = time.perf_counter() - T0
-    info["setup_s"] = setup_s
-
-    counter.armed = True
-    win = lm.serve(engine, cfg, traffic, args.seed, args.seconds,
-                   trace=bool(args.trace), profiler=profiler)
-    counter.armed = False
-    info["programs_after_window"] = lm.counts(engine)
-    mem = common.peak_bytes(devices)
-    e2e = lm.window_metrics(win, traffic)
-    e2e["setup_s"] = setup_s
-    for k in ("window_s", "due_in_window", "finished", "output_tokens",
-              "prompt_tokens", "ttft_p90_ms", "itl_p95_ms", "tokens_per_s"):
-        info[k] = e2e[k]
-    info["ttft_samples_beyond_p90"] = common.beyond(len(e2e["ttft_s"]), 0.9)
-    ctx = None
-    if args.trace:
-        ctx = lm_context(conf, traffic, win, profiler, devices)
-    # free the program's state before the reference runs (its weights and
-    # KV pool go even if a reference cycle keeps the engine object alive)
-    sched_requests = win.requests
-    win.sched = None
-    engine.params = engine.cache = None
-    del engine
-    gc.collect()
-    limit = traffic.get("correct", conf["correct"])["max_logit_gap"]
-    checks, detail = lm.check_served(conf, win, args.seed,
-                                     traffic["max_len"], limit)
-    info.update(detail)
-    # open loop: the requests due in the window; closed loop: those that
-    # emitted a token inside it
-    attempted = e2e["due_in_window"] if traffic["kind"] == "open_loop" else \
-        sum(1 for ts in win.times.values()
-            if any(win.w0 <= t < win.w1 for t in ts))
-    failed = sum(1 for r in sched_requests.values()
-                 if r.status in ("expired", "rejected"))
-    return {"e2e": e2e, "ctx": ctx, "checks": checks, "attempted": attempted,
-            "failed": failed, "memory_peak_bytes": mem, "info": info}
-
-
-def lm_context(conf, traffic, win, profiler, devices) -> dict:
-    from harness.lm import ATTN_KERNEL, DECODE_PROGRAM, KAN_KERNEL, \
-        PREFILL_PROGRAM
-    from roofline import peaks
-
-    red = profiler.reduce(kernels=(KAN_KERNEL, ATTN_KERNEL),
-                          programs=(DECODE_PROGRAM, PREFILL_PROGRAM))
-    t0, t1 = win.trace_span
-    return {"kind": "lm", "conf": conf, "traffic": traffic, "trace": red,
-            "peaks": peaks(devices[0].device_kind),
-            "steps": [s for s in win.steps if t0 <= s[1] < t1],
-            "spans": win.sched_records, "due": win.due,
-            "window": win.due_window(), "end": win.w1}
-
-
-def run_kan(conf, traffic, args, devices, counter, profiler) -> dict:
-    from harness import kan
-
-    info = {}
-    t = time.perf_counter()
-    dep, xs = kan.setup(conf, traffic, args.seed)
-    info["init_quantize_s"] = time.perf_counter() - t
-    t = time.perf_counter()
-    kan.warm_up(dep, xs)
-    info["warmup_s"] = time.perf_counter() - t
-    setup_s = time.perf_counter() - T0
-    info["setup_s"] = setup_s
-    counter.armed = True
-    win = kan.serve(dep, xs, traffic, args.seed, args.seconds,
-                    profiler=profiler)
-    counter.armed = False
-    mem = common.peak_bytes(devices)
-    e2e = kan.metrics(win, traffic)
-    e2e["setup_s"] = setup_s
-    info.update(e2e)
-    ctx = None
-    if args.trace:
-        from roofline import peaks
-
-        ctx = {"kind": "kan", "conf": conf, "traffic": traffic,
-               "trace": profiler.reduce(kernels=(kan.KERNEL,)),
-               "peaks": peaks(devices[0].device_kind)}
-    del dep
-    checks, detail = kan.check(conf, traffic, win, xs, args.seed,
-                               traffic.get("correct", conf["correct"])
-                               ["max_row_err"])
-    info.update(detail)
-    return {"e2e": e2e, "ctx": ctx, "checks": checks,
-            "attempted": win.done_in_window, "failed": 0,
-            "memory_peak_bytes": mem, "info": info}
 
 
 if __name__ == "__main__":

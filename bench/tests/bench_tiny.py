@@ -45,9 +45,12 @@ def tiny_traffic(name: str) -> dict:
 
 
 def make_root(tmp: pathlib.Path) -> pathlib.Path:
-    """BENCHMARK.json and bench/{configs,traffic,metrics} under ``tmp``."""
+    """BENCHMARK.json and bench/{configs,traffic,metrics,harness} under
+    ``tmp``; the harness is this one, linked, so that a test that patches
+    its modules patches what the run loads."""
     base = tmp / "bench"
     shutil.copytree(BENCH / "metrics", base / "metrics")
+    (base / "harness").symlink_to(BENCH / "harness", target_is_directory=True)
     (base / "configs").mkdir()
     (base / "traffic").mkdir()
     shutil.copy(BENCH.parent / "BENCHMARK.json", tmp / "BENCHMARK.json")

@@ -64,3 +64,59 @@ def test_every_entry_of_the_benchmark_has_its_files():
 def test_refuses_without_an_accelerator():
     with pytest.raises(SystemExit):
         run.accelerators(1)
+
+
+TOY_RUNNER = '''
+from __future__ import annotations
+
+import dataclasses
+import time
+
+from .common import Checks
+
+
+@dataclasses.dataclass
+class Window:          # a dataclass needs its module registered as it loads
+    calls: int = 3
+
+
+def run(conf, traffic, args, devices, counter, profiler):
+    checks = Checks()
+    checks.add("toy_err", 0.0, conf["limit"])
+    return {"e2e": {"toy_rate": 2.0 * traffic["rate"]}, "ctx": None,
+            "checks": checks, "attempted": Window().calls, "failed": 0,
+            "memory_peak_bytes": 0, "info": {"devices": len(devices)},
+            "setup_end": time.perf_counter()}
+'''
+
+
+def test_a_new_kind_is_one_new_runner_file(tmp_path, monkeypatch, capsys):
+    """A configuration of a kind the harness has never seen runs from
+    ``harness/<kind>.py`` alone, with nothing edited."""
+    import jax
+    import repro.launch.compile_cache as cache
+
+    base = tmp_path / "bench"
+    for d in ("configs", "traffic", "harness"):
+        (base / d).mkdir(parents=True)
+    (base / "configs" / "toy.json").write_text(json.dumps(
+        {"kind": "toy", "limit": 0.5}))
+    (base / "traffic" / "steady.json").write_text(json.dumps({"rate": 4}))
+    (base / "harness" / "toy.py").write_text(TOY_RUNNER)
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps({
+        "configs": [{"name": "toy", "file": "bench/configs/toy.json"}],
+        "workloads": [{"name": "toy.steady", "config": "toy",
+                       "traffic": "steady", "chips": 1}],
+        "end_to_end": [{"name": "toy_rate", "unit": "1/s"},
+                       {"name": "setup_s", "unit": "s"}],
+        "per_layer": []}))
+    monkeypatch.setattr(run, "accelerators",
+                        lambda chips: jax.devices()[:chips])
+    monkeypatch.setattr(cache, "use_compile_cache", lambda: "off (test)")
+    assert run.main(["--workload", "toy.steady", "--seed", str(2**40 + 1),
+                     "--seconds", "1", "--trace", "0"], root=tmp_path) == 0
+    res = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert res["correct"] and res["attempted"] == 3
+    assert res["metrics"]["toy_rate"] == {"value": 8.0, "unit": "1/s"}
+    assert res["metrics"]["setup_s"]["value"] > 0
+    assert list(res["checks"]) == ["toy_err", "compiles_in_window"]

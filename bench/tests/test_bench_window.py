@@ -75,9 +75,11 @@ def test_tails_cover_all_requests_and_rates_the_whole_window():
 
 
 def test_every_seed_gets_the_same_sizes_in_its_own_order():
+    """Without ``order_seed`` the run's seed draws each block's order."""
+    seeded = {k: v for k, v in CHAT.items() if k != "order_seed"}
     n = CHAT["block"]
-    a = T.plan(CHAT, 1, 2 * n)
-    b = T.plan(CHAT, 2**31 + 12345, 2 * n)
+    a = T.plan(seeded, 1, 2 * n)
+    b = T.plan(seeded, 2**31 + 12345, 2 * n)
     for lo in (0, n):
         sa = sorted(p.prompt_len for p in a[lo:lo + n])
         sb = sorted(p.prompt_len for p in b[lo:lo + n])
@@ -91,8 +93,25 @@ def test_every_seed_gets_the_same_sizes_in_its_own_order():
     assert a[n].arrival_s == pytest.approx(b[n].arrival_s)
     assert a[n].arrival_s - a[0].arrival_s == pytest.approx(
         n / CHAT["rate_per_s"])
-    gaps = np.diff([p.arrival_s for p in T.plan(CHAT, 3, 320)])
+    gaps = np.diff([p.arrival_s for p in T.plan(seeded, 3, 320)])
     assert np.mean(gaps) == pytest.approx(1 / CHAT["rate_per_s"], rel=0.05)
+
+
+@pytest.mark.parametrize("order_seed", [0, 2**40 + 9])
+def test_order_seed_fixes_the_schedule(order_seed):
+    """With ``order_seed`` every run's seed serves the same sizes and due
+    times in the same order; only the token ids follow the run's seed."""
+    t = dict(CHAT, order_seed=order_seed)
+    n = 2 * CHAT["block"]
+    a, b = T.plan(t, 1, n), T.plan(t, 2**31 + 12345, n)
+    assert [(p.prompt_len, p.output_len, p.arrival_s) for p in a] == \
+        [(p.prompt_len, p.output_len, p.arrival_s) for p in b]
+    assert T.prompt_tokens(t, 1, a[0], 1000) != \
+        T.prompt_tokens(t, 2**31 + 12345, b[0], 1000)
+    # the same sizes as any seeded block, in the order_seed's order
+    seeded = {k: v for k, v in CHAT.items() if k != "order_seed"}
+    c = T.plan(seeded, order_seed, n)
+    assert [p.prompt_len for p in a] == [p.prompt_len for p in c]
 
 
 def test_bursts_keep_the_mean_rate():
